@@ -125,6 +125,8 @@ def main() -> int:
     ap.add_argument("--smoke", action="store_true",
                     help="reduced trace + hard assertions (CI lane)")
     args = ap.parse_args()
+    from repro.launch import compile_cache
+    compile_cache.enable()
     rep = build_report(smoke=args.smoke)
     if args.out:
         with open(args.out, "w") as f:

@@ -32,7 +32,7 @@ def smoke() -> int:
     for name, spec in sorted(sort_engine.engines().items()):
         try:
             res = sort_engine.sort(x, engine=name, k=2)
-        except NotImplementedError:
+        except sort_engine.EngineUnsupported:
             continue
         perm = np.asarray(res.indices)
         if ref is None:
@@ -93,7 +93,7 @@ def smoke_faults() -> int:
         try:
             inner = sort_engine.sort(x, engine=name, k=2)
             res = sort_engine.sort(x, engine=f"resilient:{name}", k=2)
-        except NotImplementedError:
+        except sort_engine.EngineUnsupported:
             continue
         ok = (bool(np.array_equal(res.indices, inner.indices))
               and res.quality == 1.0 and not res.degraded
@@ -204,6 +204,8 @@ def main() -> None:
     ap.add_argument("--smoke-pallas", action="store_true",
                     help="fused Pallas TNS parity + perf-gate pass for CI")
     args, _ = ap.parse_known_args()
+    from repro.launch import compile_cache
+    compile_cache.enable()
 
     print("name,us_per_call,derived")
     if args.smoke:

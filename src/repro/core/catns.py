@@ -29,7 +29,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro import compat
 from repro.core import bitplane as bp
 from repro.core import tns as jt
 
@@ -257,7 +256,7 @@ def multibank_sort_planes(digits: jnp.ndarray,
         sign_bits = jnp.zeros(N, dtype=bool)
 
     @functools.partial(
-        compat.shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(P(None, axis), P(axis)),
         out_specs=(P(axis), P(), P(), P()),
     )
@@ -266,7 +265,7 @@ def multibank_sort_planes(digits: jnp.ndarray,
         kk = max(k, 1)
         step = _mb_body(digits_l, sign_l if have_sign else None,
                         fmt, ascending, level_bits, axis)
-        vary = lambda x: compat.pcast_varying(x, axis)
+        vary = lambda x: jax.lax.pcast(x, (axis,), to="varying")
         init = MbCarry(
             alive=vary(jnp.ones(Nl, bool)), valid=vary(jnp.ones(Nl, bool)),
             col=jnp.int32(0),

@@ -40,8 +40,9 @@ def cell_key(fmt: str, n: int, m: int, b: int,
 
 def candidate_params(b: int) -> List[Dict[str, int]]:
     """The sweep lattice: block heights that divide into the batch
-    usefully, crossed with episode unroll factors."""
-    rows = [r for r in (0, 16, 8, 1) if r == 0 or r < b]
+    usefully, crossed with episode unroll factors.  A block shorter than
+    the batch spans whole 8-row sublane tiles, so 8 is the smallest."""
+    rows = [r for r in (0, 16, 8) if r == 0 or r < b]
     return [{"block_rows": r, "unroll": u} for r in rows for u in (1, 2, 4)]
 
 

@@ -16,7 +16,8 @@ This module centralizes the decision:
 
 Resolution order: the ``REPRO_PALLAS`` environment variable
 (``compiled`` / ``interpret`` / ``jnp``) wins; otherwise the default JAX
-backend picks (``tpu``/``gpu`` -> compiled, anything else -> interpret).
+backend picks (``tpu``/``gpu`` -> compiled, ``cpu`` -> interpret).  A
+backend that fails to start raises; it never turns into interpret mode.
 The result is cached — backends don't change mid-process — but
 :func:`reset` clears the cache for tests.
 """
@@ -42,11 +43,11 @@ def mode() -> str:
                     f"REPRO_PALLAS={env!r}; expected one of {_VALID}")
             _cached_mode = env
         else:
-            try:
-                backend = jax.default_backend()
-            except RuntimeError:          # no backend at all
-                backend = ""
-            _cached_mode = ("compiled" if backend in ("tpu", "gpu")
+            # no try/except: a process that cannot start its backend (a
+            # chip another process holds, say) must fail here, not
+            # quietly run every kernel in interpret mode
+            _cached_mode = ("compiled"
+                            if jax.default_backend() in ("tpu", "gpu")
                             else "interpret")
     return _cached_mode
 
@@ -68,17 +69,16 @@ def use_ref(interpret: Optional[bool] = None) -> bool:
 
 
 def env_stamp() -> dict:
-    """Provenance stamp for benchmark artifacts: which backend, JAX
-    version and Pallas dispatch mode produced the numbers.  Every BENCH_*
-    writer embeds this so a committed artifact can be told apart from a
-    rerun on different hardware (a compiled-TPU baseline must not gate an
-    interpret-CPU run, and vice versa)."""
-    try:
-        backend = jax.default_backend()
-    except RuntimeError:
-        backend = "none"
+    """Provenance stamp for benchmark artifacts: which backend, chip,
+    device count, JAX version and Pallas dispatch mode produced the
+    numbers.  Every BENCH_* writer embeds this so a committed artifact can
+    be told apart from a rerun on different hardware (a compiled-TPU
+    baseline must not gate an interpret-CPU run, and vice versa)."""
+    devices = jax.devices()
     return {
-        "backend": backend,
+        "backend": jax.default_backend(),
+        "device_kind": devices[0].device_kind,
+        "device_count": len(devices),
         "jax_version": jax.__version__,
         "pallas_mode": mode(),
     }

@@ -35,8 +35,8 @@ def _pack_i32_kernel(x_ref, o_ref):
     o_ref[...] = jax.lax.bitcast_convert_type(x, jnp.uint32) ^ jnp.uint32(0x80000000)
 
 
-def _blocked_elementwise(kernel, x, out_dtype, block=(256, 512),
-                         interpret=True):
+def _blocked_elementwise(kernel, x, out_dtype, *, interpret: bool,
+                         block=(256, 512)):
     orig_shape = x.shape
     flat = x.reshape(-1)
     n = flat.shape[0]

@@ -10,8 +10,9 @@ kernel walks the W planes with the NE mask in vector registers and returns
 * the number of *useful* DRs (mixed reads, i.e. reads that caused a number
   exclusion) — the quantity TNS tries to minimize.
 
-One grid program per batch row; the full (W, N) tile stays in VMEM
-(W<=32, N<=64k => <=2 MB of uint8, well inside the 16 MB VMEM budget).
+The wrapper packs each lane's digit column into one int32 word; a grid
+program walks the planes of 8 batch rows with their (8, N) word tile in
+VMEM (N<=64k => 2 MB of int32, inside the 16 MB VMEM budget).
 """
 from __future__ import annotations
 
@@ -40,23 +41,38 @@ def pad_to(x: jnp.ndarray, shape, fill) -> jnp.ndarray:
     return jnp.pad(x, cfg, constant_values=fill)
 
 
-def _dr_kernel(planes_ref, mask_ref, drs_ref, *, width: int, n_valid: int,
+def pack_columns(planes: jnp.ndarray) -> jnp.ndarray:
+    """(B, W, N) 0/1 bit planes -> (B, N) int32: each lane's digit column
+    packed into one word, column 0 in the most significant of its W bits
+    (W <= 32).  The kernels read the array image in this form — one 32-bit
+    lane per number instead of W uint8 sublanes, which Mosaic cannot slice."""
+    planes = (planes != 0).astype(jnp.int32)
+    word = planes[:, 0, :]
+    for c in range(1, planes.shape[1]):
+        word = (word << 1) | planes[:, c, :]
+    return word
+
+
+def _dr_kernel(word_ref, mask_ref, drs_ref, *, width: int, n_valid: int,
                ascending: bool):
-    planes = planes_ref[0]                                  # (W, N) uint8
-    w, n = planes.shape
-    lane = jax.lax.broadcasted_iota(jnp.int32, (n,), 0)
-    valid = lane < n_valid
-    useful = jnp.zeros((), dtype=jnp.int32)
-    exc = jnp.uint8(1) if ascending else jnp.uint8(0)
+    words = word_ref[...]                        # (bm, N) packed columns
+    bm, n = words.shape
+    lane = jax.lax.broadcasted_iota(jnp.int32, (bm, n), 1)
+    # the survivor mask stays int32 0/1: Mosaic cannot select between
+    # bool vectors
+    valid = (lane < n_valid).astype(jnp.int32)
+    useful = jnp.zeros((bm, 1), dtype=jnp.int32)
+    exc = 1 if ascending else 0
     for col in range(width):
-        row = planes[col]
-        hit = valid & (row == exc)
-        keep = valid & (row != exc)
-        mixed = jnp.any(hit) & jnp.any(keep)
-        valid = jnp.where(mixed, keep, valid)
-        useful = useful + mixed.astype(jnp.int32)
-    mask_ref[0] = valid
-    drs_ref[0, 0] = useful
+        row = (words >> (width - 1 - col)) & 1
+        hit = jnp.where(row == exc, valid, 0)
+        keep = valid - hit
+        mixed = (jnp.max(hit, axis=1, keepdims=True)
+                 & jnp.max(keep, axis=1, keepdims=True))   # (bm, 1) 0/1
+        valid = jnp.where(mixed != 0, keep, valid)
+        useful = useful + mixed
+    mask_ref[...] = valid
+    drs_ref[...] = useful
 
 
 @functools.partial(jax.jit, static_argnames=("ascending", "interpret"))
@@ -71,19 +87,21 @@ def min_search(planes: jnp.ndarray, ascending: bool = True,
     interpret = backend.use_interpret(interpret)
     assert planes.ndim == 3 and planes.dtype == jnp.uint8
     b, w, n = planes.shape
+    assert w <= 32, "a lane's digit column is packed into one 32-bit word"
     n_pad = pad_lanes(n)
-    # ascending pads with 1s so padding never wins a min search (the
-    # kernel's `valid` lane mask already excludes it; the fill just keeps
-    # the all-0's/1's checks honest on the padded tail)
-    planes_p = pad_to(planes, (b, w, n_pad), 1 if ascending else 0)
+    bm = min(b, 8)                  # whole 8-row sublane tiles per program
+    b_pad = -(-b // bm) * bm
+    # padded lanes sit outside the kernel's `valid` mask, so their fill
+    # never takes part in a search
+    words = pad_to(pack_columns(planes), (b_pad, n_pad), 0)
     mask, drs = pl.pallas_call(
         functools.partial(_dr_kernel, width=w, n_valid=n, ascending=ascending),
-        grid=(b,),
-        in_specs=[pl.BlockSpec((1, w, n_pad), lambda i: (i, 0, 0))],
-        out_specs=[pl.BlockSpec((1, n_pad), lambda i: (i, 0)),
-                   pl.BlockSpec((1, 1), lambda i: (i, 0))],
-        out_shape=[jax.ShapeDtypeStruct((b, n_pad), jnp.bool_),
-                   jax.ShapeDtypeStruct((b, 1), jnp.int32)],
+        grid=(b_pad // bm,),
+        in_specs=[pl.BlockSpec((bm, n_pad), lambda i: (i, 0))],
+        out_specs=[pl.BlockSpec((bm, n_pad), lambda i: (i, 0)),
+                   pl.BlockSpec((bm, 1), lambda i: (i, 0))],
+        out_shape=[jax.ShapeDtypeStruct((b_pad, n_pad), jnp.int32),
+                   jax.ShapeDtypeStruct((b_pad, 1), jnp.int32)],
         interpret=interpret,
-    )(planes_p)
-    return mask[:, :n], drs[:, 0]
+    )(words)
+    return mask[:b, :n] != 0, drs[:b, 0]

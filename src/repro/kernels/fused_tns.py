@@ -4,10 +4,12 @@ winner write-back, all inside ONE ``pl.pallas_call``.
 The cycle-faithful machines in :mod:`repro.core.tns` interpret the paper's
 controller one cycle per ``while_loop`` trip — every digit decision is a
 round-trip through the (dynamically bounded) loop carry.  This kernel
-keeps the whole (W, N) bit-plane tile resident in VMEM and replays the
-SAME controller at *emission-episode* granularity with a statically
-structured loop, so it compiles to straight-line vector code on TPU and
-to a short fori_loop on CPU interpret mode.
+keeps the whole array image resident in VMEM — each lane's W-bit digit
+column packed into one int32 word by the wrapper (MSB = column 0, the
+sign plane at bit 30) — and replays the SAME controller at
+*emission-episode* granularity with a statically structured loop, so it
+compiles to straight-line vector code on TPU and to a short fori_loop on
+CPU interpret mode.
 
 Episode model (mechanically equivalent to ``core/tns.py``; parity of the
 permutation AND of all three observables — cycles, DRs, redundant reload
@@ -31,9 +33,9 @@ cycles — is asserted in tests/test_fused_tns.py):
   one per controller cycle — ``max(0, d-1)`` of those cycles are the
   paper's redundant reload cycles).  A live resumed node stays present,
   exactly like the hardware LIFO.
-* One *episode* = reload + descent + emission.  Each lane's digit column
-  is packed into one W-bit integer key (MSB = column 0), so the whole
-  descent is closed-form integer arithmetic: the machine keeps digit
+* One *episode* = reload + descent + emission.  With each lane's digit
+  column one W-bit integer key, the whole descent is closed-form integer
+  arithmetic: the machine keeps digit
   ``~exc`` at every split, hence its winner tie-set is the argmin of
   ``key ^ flip`` over the resumed set (``flip`` = kept-digit word,
   prefix holes masked out of the comparison), the DR count is the span
@@ -50,7 +52,7 @@ cycles — is asserted in tests/test_fused_tns.py):
 Outputs are an inverse-permutation ring (rank[i] = emission slot of
 element i) plus per-instance counters; the wrapper scatters rank into the
 forward permutation.  ``level_bits > 1`` stays on the while_loop machine
-(NotImplementedError here, same restriction as the packed fast path).
+(EngineUnsupported here, same restriction as the packed fast path).
 
 Dispatch: compiled on TPU/GPU, ``interpret`` on CPU, and under
 ``REPRO_PALLAS=jnp`` the oracle path reuses ``tns_sort_planes_batched``
@@ -65,10 +67,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import bitplane as bp
 from repro.kernels import backend
-from repro.kernels.digit_read import pad_lanes, pad_to
+from repro.kernels.digit_read import pack_columns, pad_lanes, pad_to
 
 
 class FusedOut(NamedTuple):
@@ -82,6 +85,7 @@ class FusedOut(NamedTuple):
 # counter columns written by the kernel
 _CYC, _DRS, _RLC, _UDR, _OUT = range(5)
 _NCNT = 8          # counter block padded to 8 lanes
+_SIGN_BIT = 30     # sign plane's bit in a lane's packed word
 
 
 def _flip_mask(fmt: str, ascending: bool, width: int, neg_pend):
@@ -120,62 +124,71 @@ def _bitlength(x: jnp.ndarray, width: int) -> jnp.ndarray:
     return jax.lax.population_count(sm)
 
 
-def _or_reduce(x: jnp.ndarray, axis: int) -> jnp.ndarray:
-    """Bitwise-OR reduction along ``axis`` (no jnp ufunc .reduce in this
-    jax version; ``lax.reduce`` with an OR monoid lowers everywhere)."""
-    return jax.lax.reduce(x, np.int32(0), lambda a, b: a | b, (axis,))
+def _lane_or(x: jnp.ndarray) -> jnp.ndarray:
+    """Bitwise OR across the lanes of a (bm, Np) int32 tile (Np a multiple
+    of 128) as a (bm, 1) column: OR the 128-lane chunks together, then a
+    log-step rotate-and-OR leaves the total in every lane of the tile."""
+    acc = x[:, :128]
+    for c in range(128, x.shape[1], 128):
+        acc = acc | x[:, c:c + 128]
+    for sh in (64, 32, 16, 8, 4, 2, 1):
+        acc = acc | pltpu.roll(acc, sh, 1)
+    return jnp.max(acc, axis=1, keepdims=True)
+
+
+def _lowest_bits(x: jnp.ndarray, k: int, width: int) -> jnp.ndarray:
+    """The ``k`` lowest set bits of each word of ``x``."""
+    keep = jnp.zeros_like(x)
+    for _ in range(min(k, width)):
+        low = x & -x
+        keep = keep | low
+        x = x ^ low
+    return keep
 
 
 def _exclusive_prefix(x: jnp.ndarray) -> jnp.ndarray:
-    """Exclusive prefix sum of 0/1 counts along the last axis (length a
-    multiple of 32).  Decomposed as a within-word prefix by a strict
-    lower-triangular matmul (the dot materializes, so XLA's pointwise
-    fusion cannot turn the prefix into an exponential recompute tree —
-    which is exactly what happens to the classic log-step shifted-add
-    chain on CPU) plus a short shifted-add prefix across words.  No
-    ``cumsum``: Mosaic does not lower it along the lane axis."""
+    """Exclusive prefix sum of 0/1 counts along the lanes of a (b, Np)
+    tile, Np a multiple of 128.  Each 128-lane chunk takes its in-chunk
+    prefix from one matmul with a strict upper-triangular 0/1 matrix (exact
+    in f32; the MXU does it), plus the running total of earlier chunks.
+    No ``cumsum``: Mosaic does not lower it along the lane axis."""
     b, n = x.shape
-    nw = n // 32
-    x3 = x.reshape(b, nw, 32)
-    tri = jnp.tril(jnp.ones((32, 32), jnp.float32), -1)      # tri[i,j]: j<i
-    plow = jax.lax.dot_general(
-        x3.astype(jnp.float32), tri,
-        dimension_numbers=(((2,), (1,)), ((), ()))).astype(x.dtype)
-    wsum = jnp.sum(x3, axis=2)                                # (b, nw)
-    inc = wsum
-    shift = 1
-    while shift < nw:
-        z = jnp.zeros((b, shift), wsum.dtype)
-        inc = inc + jnp.concatenate([z, inc[:, :-shift]], axis=-1)
-        shift *= 2
-    wpre = inc - wsum
-    return (plow + wpre[:, :, None]).reshape(b, n)
+    row = jax.lax.broadcasted_iota(jnp.int32, (128, 128), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (128, 128), 1)
+    tri = (row < col).astype(jnp.float32)          # tri[i, j]: i < j
+    base = jnp.zeros((b, 1), jnp.float32)
+    parts = []
+    for c in range(0, n, 128):
+        xc = x[:, c:c + 128].astype(jnp.float32)
+        parts.append(jnp.dot(xc, tri, preferred_element_type=jnp.float32)
+                     + base)
+        base = base + jnp.sum(xc, axis=1, keepdims=True)
+    return jnp.concatenate(parts, axis=1).astype(x.dtype)
 
 
-def _fused_tns_kernel(planes_ref, sign_ref, rank_ref, cnt_ref, *,
+def _fused_tns_kernel(word_ref, rank_ref, cnt_ref, *,
                       width: int, n_valid: int, k: int, fmt: str,
                       ascending: bool, stop_n: int, unroll: int):
-    planes = planes_ref[...]                       # (bm, W, Np) uint8
-    bm, W, Np = planes.shape
+    # Per-instance state lives in (bm, 1) int32 columns, and the LIFO's
+    # per-column flags in W-bit words (bit W-1-c = column c), so every
+    # value in the loop is a 2-D 32-bit vector Mosaic can lay out.
+    words = word_ref[...]                          # (bm, Np) int32
+    bm, Np = words.shape
+    W = width
+    wmask = (1 << W) - 1
+    key = words & wmask          # lane's digit column, MSB = column 0
     lane = jax.lax.broadcasted_iota(jnp.int32, (bm, Np), 1)
-    iota_w = jax.lax.broadcasted_iota(jnp.int32, (bm, W), 1)
+    valid = lane < n_valid
     signed = fmt in (bp.SIGNMAG, bp.FLOAT)
     if signed:
-        sign = sign_ref[...] != 0                  # (bm, Np) bool
+        sign = ((words >> _SIGN_BIT) & 1) != 0     # (bm, Np) bool
         sign_dir = sign if ascending else ~sign
-    wmask = (1 << W) - 1
     imax = jnp.iinfo(jnp.int32).max    # sentinel above any masked key
-    # pack each lane's digit column into one W-bit word, MSB = column 0:
-    # every descent below is integer arithmetic on these keys (unrolled
-    # shift-or: ~20x cheaper than a broadcast multiply + axis reduce)
-    key = planes[:, 0, :].astype(jnp.int32)
-    for c in range(1, W):
-        key = (key << 1) | planes[:, c, :].astype(jnp.int32)
 
     def episode(carry):
-        alive, pathv, skipv, present, rank, out, cyc, drs, rlc, udr = carry
-        running = out < stop_n                                     # (bm,)
-        run2 = running[:, None]
+        pathv, skipv, present, rank, out, cyc, drs, rlc, udr = carry
+        alive = valid & (rank < 0)
+        running = out < stop_n                                   # (bm, 1)
 
         # ---- reload: pop drained nodes, resume the deepest live one.
         # A node at column c is live iff some alive element matches the
@@ -183,30 +196,31 @@ def _fused_tns_kernel(planes_ref, sign_ref, rank_ref, cnt_ref, *,
         # anything): lane match depth = leading agreement of key with the
         # path word, holes masked out.
         if k > 0:
-            md = (key ^ pathv[:, None]) & (~skipv & wmask)[:, None]
+            md = (key ^ pathv) & (~skipv & wmask)
             depth = W - _bitlength(md, W)
-            c_max = jnp.max(jnp.where(alive, depth, 0), axis=1)
-            live_lvl = present & (iota_w <= c_max[:, None])
-            c_res = jnp.max(jnp.where(live_lvl, iota_w, -1), axis=1)
-            drained = present & (iota_w > c_res[:, None])
-            d = jnp.sum(drained.astype(jnp.int32), axis=1)
+            c_max = jnp.max(jnp.where(alive, depth, 0), axis=1,
+                            keepdims=True)
+            # present columns c <= c_max, i.e. bits >= W-1-c_max
+            live = present & ~((1 << jnp.maximum(W - 1 - c_max, 0)) - 1)
+            # the deepest live column is the lowest set bit (-1: none)
+            c_res = jnp.where(live == 0, -1, W - _bitlength(live & -live, W))
+            pos_res = W - 1 - c_res                # c_res == -1 -> W
+            below = (1 << pos_res) - 1             # columns deeper than c_res
+            d = jax.lax.population_count(present & below)
             spent = jnp.where(running, jnp.maximum(d - 1, 0), 0)
-            present = jnp.where(run2, present & (iota_w <= c_res[:, None]),
-                                present)
-            m0 = alive & (depth >= c_res[:, None])
+            present = jnp.where(running, present & ~below, present)
+            m0 = alive & (depth >= c_res)
             # the resumed column holds the PRE-exclusion set: it stops
             # filtering (a prefix hole) until a later descent re-reads it.
             # Holes above c_res belong to popped subtrees — drop them so
             # the masked comparison below sees those columns again.
-            pos_res = W - 1 - c_res                # c_res == -1 -> W
-            keepm = ~((1 << pos_res) - 1)
             resume = jnp.where(c_res >= 0, 1 << pos_res, 0)
-            skipv = jnp.where(running, (skipv & keepm) | resume, skipv)
+            skipv = jnp.where(running, (skipv & ~below) | resume, skipv)
             col0 = c_res + 1            # restart (c_res == -1) -> column 0
             cyc = cyc + spent
             rlc = rlc + spent
         else:
-            col0 = jnp.zeros((bm,), jnp.int32)
+            col0 = jnp.zeros((bm, 1), jnp.int32)
             m0 = alive
 
         # ---- descent: the machine reads columns col0.. while >1 valid
@@ -216,30 +230,28 @@ def _fused_tns_kernel(planes_ref, sign_ref, rank_ref, cnt_ref, *,
         # depths (first set bit of XOR vs the winner) replay the DR /
         # mixed-read / push sequence without walking the columns.
         if signed:
-            neg_pend = jnp.any(alive & sign_dir, axis=1)
+            neg_pend = jnp.max(jnp.where(alive & sign_dir, 1, 0), axis=1,
+                               keepdims=True) > 0
         else:
-            neg_pend = jnp.zeros((bm,), dtype=bool)
+            neg_pend = jnp.zeros((bm, 1), dtype=bool)
         flipv = _flip_mask(fmt, ascending, W, neg_pend)
-        if k > 0:
-            cmask = (~skipv & wmask)[:, None]
-        else:
-            cmask = wmask
-        ckey = jnp.where(m0, (key ^ flipv[:, None]) & cmask, imax)
-        kmin = jnp.min(ckey, axis=1)
-        isw = ckey == kmin[:, None]                # winner tie-set
-        t = jnp.sum(isw.astype(jnp.int32), axis=1)
-        bl = _bitlength(ckey ^ kmin[:, None], W)   # 0 for winners
+        cmask = (~skipv & wmask) if k > 0 else wmask
+        ckey = jnp.where(m0, (key ^ flipv) & cmask, imax)
+        kmin = jnp.min(ckey, axis=1, keepdims=True)
+        isw = ckey == kmin                         # winner tie-set
+        t = jnp.sum(isw.astype(jnp.int32), axis=1, keepdims=True)
+        bl = _bitlength(ckey ^ kmin, W)            # 0 for winners
         loser = m0 & ~isw
         # deepest column still read = last with >=2 contenders left: W-1
         # when the winner itself is a tie, else the deepest divergence
-        dm = jnp.max(jnp.where(loser, W - bl, -1), axis=1)
+        dm = jnp.max(jnp.where(loser, W - bl, -1), axis=1, keepdims=True)
         cend = jnp.minimum(jnp.where(t >= 2, W, dm), W - 1)
         ep_drs = jnp.where(running, jnp.maximum(cend - col0 + 1, 0), 0)
         rm = jnp.where(running & (cend >= col0),
                        (1 << (W - col0)) - (1 << (W - 1 - cend)), 0)
         # mixed-read columns = divergence bits of losers in the read range
         hib = 1 << jnp.maximum(bl - 1, 0)          # loser's divergence bit
-        ebits = _or_reduce(jnp.where(loser, hib, 0), 1) & rm
+        ebits = _lane_or(jnp.where(loser, hib, 0)) & rm
         udr = udr + jax.lax.population_count(ebits)
         if k > 0:
             # a read refreshes the path digit (the winner's bit) and
@@ -249,23 +261,16 @@ def _fused_tns_kernel(planes_ref, sign_ref, rank_ref, cnt_ref, *,
                               (pathv & ~rm) | ((kmin ^ flipv) & rm), pathv)
             # state-record pushes at the mixed columns; at capacity k the
             # shallowest present column (the LIFO's oldest entry) drops
-            # first, so the survivors are the deepest k of old + new
-            mixed_w = ((ebits[:, None] >> (W - 1 - iota_w)) & 1) != 0
-            union = present | mixed_w
-            sfx = union.astype(jnp.int32)          # suffix count per col
-            sh = 1
-            while sh < W:
-                sfx = sfx + jnp.concatenate(
-                    [sfx[:, sh:], jnp.zeros((bm, sh), jnp.int32)], axis=1)
-                sh *= 2
-            present = jnp.where(run2, union & (sfx <= k), present)
+            # first, so the survivors are the deepest k (lowest bits) of
+            # old + new
+            present = jnp.where(running, _lowest_bits(present | ebits, k, W),
+                                present)
 
         # ---- emission: whole tie set, consecutive index-order ranks ----
         r = jnp.minimum(t, jnp.maximum(stop_n - out, 0))
         p = _exclusive_prefix(isw.astype(jnp.int32))
-        emit_now = isw & (p < r[:, None]) & run2
-        rank = jnp.where(emit_now, out[:, None] + p, rank)
-        alive = alive & ~emit_now
+        emit_now = isw & (p < r) & running
+        rank = jnp.where(emit_now, out + p, rank)
         out = out + jnp.where(running, r, 0)
         # zero reads: the set came straight off the LIFO — a lone number
         # costs its last-number-check cycle, ties drain one per repeat
@@ -275,29 +280,36 @@ def _fused_tns_kernel(planes_ref, sign_ref, rank_ref, cnt_ref, *,
                              jnp.maximum(r - 1, 0))
         cyc = cyc + jnp.where(running, emit_cyc, 0) + ep_drs
         drs = drs + ep_drs
-        return (alive, pathv, skipv, present, rank,
-                out, cyc, drs, rlc, udr)
+        return (pathv, skipv, present, rank, out, cyc, drs, rlc, udr)
 
     def body(_, carry):
         for _u in range(max(1, unroll)):
             carry = episode(carry)
         return carry
 
-    zero = jnp.zeros((bm,), jnp.int32)
-    init = (lane < n_valid,                                   # alive
-            zero,                                             # path word
-            zero,                                             # skip word
-            jnp.zeros((bm, W), dtype=bool),                   # present
-            jnp.full((bm, Np), -1, jnp.int32),                # rank
+    zero = jnp.zeros((bm, 1), jnp.int32)
+    init = (zero,                                  # path word
+            zero,                                  # skip word
+            zero,                                  # present word
+            jnp.full((bm, Np), -1, jnp.int32),     # rank
             zero, zero, zero, zero, zero)
     trips = -(-stop_n // max(1, unroll))
     carry = jax.lax.fori_loop(0, trips, body, init)
-    rank = carry[4]
-    out, cyc, drs, rlc, udr = carry[5:]
-    rank_ref[...] = rank
-    pad = jnp.zeros((bm,), jnp.int32)
-    cnt_ref[...] = jnp.stack(
-        [cyc, drs, rlc, udr, out, pad, pad, pad], axis=1)
+    rank_ref[...] = carry[3]
+    li = jax.lax.broadcasted_iota(jnp.int32, (bm, _NCNT), 1)
+    cnt = jnp.zeros((bm, _NCNT), jnp.int32)
+    for col, v in zip((_CYC, _DRS, _RLC, _UDR, _OUT),
+                      (carry[5], carry[6], carry[7], carry[8], carry[4])):
+        cnt = jnp.where(li == col, v, cnt)
+    cnt_ref[...] = cnt
+
+
+def _block_rows(block_rows: Optional[int], b: int) -> int:
+    """Instances per grid program.  A block shorter than the batch must
+    span whole 8-row sublane tiles, so it rounds up to a multiple of 8."""
+    if block_rows is None or block_rows >= b:
+        return b
+    return min(b, -(-max(1, block_rows) // 8) * 8)
 
 
 @functools.partial(
@@ -315,32 +327,30 @@ def _fused_tns_rank(planes: jnp.ndarray,
     is element i's emission slot, -1 if never emitted."""
     interpret = backend.use_interpret(interpret)
     assert planes.ndim == 3, "fused_tns_planes expects (B, W, N) planes"
-    assert planes.shape[1] < 31, "digit keys are packed into int32 words"
-    planes = (planes != 0).astype(jnp.uint8)
+    assert planes.shape[1] <= _SIGN_BIT, "digit keys are packed into int32"
     B, W, N = planes.shape
     stop_n = N if stop_after is None else min(stop_after, N)
     stop_n = max(stop_n, 1)
     Np = pad_lanes(N)
-    bm = B if block_rows is None else max(1, min(block_rows, B))
+    bm = _block_rows(block_rows, B)
     b_pad = -(-B // bm) * bm
-    planes_p = pad_to(planes, (b_pad, W, Np), 0)
-    if sign_bits is None:
-        sign_p = jnp.zeros((b_pad, Np), dtype=jnp.uint8)
-    else:
-        sign_p = pad_to(sign_bits.astype(jnp.uint8), (b_pad, Np), 0)
+    # one int32 word per lane: the digit column (MSB = column 0) and the
+    # sign plane above it
+    words = pack_columns(planes)
+    if sign_bits is not None:
+        words = words | ((sign_bits != 0).astype(jnp.int32) << _SIGN_BIT)
     rank, cnt = pl.pallas_call(
         functools.partial(_fused_tns_kernel, width=W, n_valid=N, k=k,
                           fmt=fmt, ascending=ascending, stop_n=stop_n,
                           unroll=unroll),
         grid=(b_pad // bm,),
-        in_specs=[pl.BlockSpec((bm, W, Np), lambda i: (i, 0, 0)),
-                  pl.BlockSpec((bm, Np), lambda i: (i, 0))],
+        in_specs=[pl.BlockSpec((bm, Np), lambda i: (i, 0))],
         out_specs=[pl.BlockSpec((bm, Np), lambda i: (i, 0)),
                    pl.BlockSpec((bm, _NCNT), lambda i: (i, 0))],
         out_shape=[jax.ShapeDtypeStruct((b_pad, Np), jnp.int32),
                    jax.ShapeDtypeStruct((b_pad, _NCNT), jnp.int32)],
         interpret=interpret,
-    )(planes_p, sign_p)
+    )(pad_to(words, (b_pad, Np), 0))
     return rank[:B, :N], cnt[:B]
 
 
@@ -368,7 +378,7 @@ def fused_tns_planes(planes: jnp.ndarray,
                      interpret: bool | None = None) -> FusedOut:
     """Run the fused TNS kernel on (B, W, N) bit planes (MSB first, the
     physical array image).  One grid program sorts ``block_rows``
-    instances with their (W, N) tiles resident in VMEM.  Cycle / DR /
+    instances with their packed (N,) word rows resident in VMEM.  Cycle / DR /
     reload counts match :func:`repro.core.tns.tns_sort_planes` exactly;
     ``useful_drs`` additionally counts only the mixed reads.
     ``interpret=None`` resolves per backend."""
@@ -395,7 +405,8 @@ def fused_tns_sort(values, *, width: int, k: int, fmt: str = bp.UNSIGNED,
     fault-injectable ``bitplane.read_planes`` path) and run the fused
     kernel — or, under ``REPRO_PALLAS=jnp``, the while_loop oracle."""
     if level_bits != 1:
-        raise NotImplementedError(
+        from repro.sort.registry import EngineUnsupported
+        raise EngineUnsupported(
             "fused Pallas TNS runs binary (level_bits=1) planes; "
             "multi-level stays on the while_loop machine")
     x = np.asarray(values)

@@ -11,9 +11,9 @@ program handles a (BM, N) row tile; N stays resident in VMEM (router sizes:
 N = #experts <= a few hundred; we pad N to the 128-lane boundary with
 0xFFFFFFFF sentinels).  k and r are compile-time constants.
 
-Digit presence is computed with a static loop of masked any-reductions —
-2^r vector reductions per digit, no (BM, N, 2^r) intermediate, keeping the
-VMEM working set at O(BM * N).
+The smallest digit present among the survivors is one masked min-reduction
+per digit, with no (BM, N, 2^r) intermediate, keeping the VMEM working set
+at O(BM * N).
 """
 from __future__ import annotations
 
@@ -39,25 +39,27 @@ def _topk_kernel(keys_ref, idx_ref, key_ref, *, k: int, r: int, n_valid: int):
     bm, n = keys.shape
     R = 1 << r
     lane = jax.lax.broadcasted_iota(jnp.int32, (bm, n), 1)
-    valid0 = lane < n_valid
-    valid = valid0
+    col = jax.lax.broadcasted_iota(jnp.int32, (bm, k), 1)
+    valid = lane < n_valid
+    idx_out = jnp.zeros((bm, k), jnp.int32)
+    key_out = jnp.zeros((bm, k), jnp.uint32)
     for j in range(k):
         m = valid
-        min_key = jnp.zeros((bm,), dtype=jnp.uint32)
+        min_key = jnp.zeros((bm, 1), dtype=jnp.uint32)
         for shift in range(KEY_BITS - r, -1, -r):
             dig = ((keys >> shift) & (R - 1)).astype(jnp.int32)
-            # presence[v] = any(m & dig==v): DR + "all 0's/1's" periphery
-            pres = []
-            for v in range(R):
-                pres.append(jnp.any(m & (dig == v), axis=1))
-            presence = jnp.stack(pres, axis=1)             # (BM, R)
-            dmin = jnp.argmax(presence, axis=1).astype(jnp.int32)
-            m = m & (dig == dmin[:, None])                 # number exclusion
+            # smallest digit present among the survivors: DR + the
+            # "all 0's/1's" periphery, one lane reduction per digit
+            dmin = jnp.min(jnp.where(m, dig, R), axis=1, keepdims=True)
+            m = m & (dig == dmin)                          # number exclusion
             min_key = min_key | (dmin.astype(jnp.uint32) << shift)
-        chosen = jnp.argmax(m, axis=1).astype(jnp.int32)   # first of ties
-        idx_ref[:, j] = chosen
-        key_ref[:, j] = min_key
-        valid = valid & (lane != chosen[:, None])
+        # first of ties: the lowest surviving lane
+        chosen = jnp.min(jnp.where(m, lane, n), axis=1, keepdims=True)
+        idx_out = jnp.where(col == j, chosen, idx_out)
+        key_out = jnp.where(col == j, min_key, key_out)
+        valid = valid & (lane != chosen)
+    idx_ref[...] = idx_out
+    key_ref[...] = key_out
 
 
 @functools.partial(jax.jit, static_argnames=("k", "r", "block_rows",

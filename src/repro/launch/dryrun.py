@@ -1,16 +1,18 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run: lower + compile every (architecture x input shape)
 on the production meshes, print memory/cost analyses, extract roofline
-terms.  MUST be run as its own process (the XLA flag above is set before
-any jax import and locks the device count).
+terms.  A CPU-only compile tool: it MUST be run as its own process,
+because it pins JAX to the CPU and forces 512 host devices before JAX is
+imported (on a host with a chip it would otherwise take the chip).
 
 Usage:
     PYTHONPATH=src python -m repro.launch.dryrun --arch qwen3_14b \
         --shape train_4k [--multi-pod] [--out experiments/dryrun]
     PYTHONPATH=src python -m repro.launch.dryrun --all [--multi-pod]
 """
+import os
+os.environ["JAX_PLATFORMS"] = "cpu"
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+
 import argparse
 import json
 import time

@@ -10,14 +10,12 @@ from typing import Tuple
 
 import jax
 
-from repro import compat
-
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 = 256 chips per pod; 2x16x16 = 512 chips across two pods."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def data_axes(mesh) -> Tuple[str, ...]:
@@ -35,4 +33,9 @@ def make_host_mesh(model_parallel: int = 1):
     mp = model_parallel
     while mp > 1 and n % mp:
         mp //= 2
-    return compat.make_mesh((n // mp, mp), ("data", "model"))
+    return _auto_mesh((n // mp, mp), ("data", "model"))
+
+
+def _auto_mesh(shape, axes):
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))

@@ -31,6 +31,7 @@ from typing import Dict, Optional
 import numpy as np
 
 from repro import serving, sort as sort_engine
+from repro.launch import compile_cache
 from repro.runtime import faults
 from repro.runtime.faults import run_step_with_retries
 
@@ -152,6 +153,7 @@ def serve(cfg, batch: int, prompt_len: int, max_new: int,
             decode_s = time.monotonic() - t0
     return {
         "tokens": np.asarray(seq),
+        "logits": np.asarray(logits[:, -1, :]),   # the last sampled step's
         "prefill_s": prefill_s,
         "decode_tok_per_s": batch * (max_new - 1) / max(decode_s, 1e-9),
         "pruned": prune_rate,
@@ -192,13 +194,14 @@ def _oneshot_main(args) -> None:
                       f"{probe.quality:.3f} repairs={probe.repairs} "
                       f"retries={probe.retries} degraded={probe.degraded}")
                 if probe.degraded:
-                    raise RuntimeError("fault pre-flight degraded")
+                    raise faults.VerificationError(
+                        "fault pre-flight degraded")
                 return serve(cfg, args.batch, args.prompt_len, args.max_new,
                              top_k=args.top_k, prune_rate=args.prune)
 
         res = run_step_with_retries(
             attempt, retries=args.serve_retries, backoff_s=0.05,
-            on_retry=lambda i, e: print(f"[serve] retry {i + 1}: {e}"),
+            retry_on=(faults.FaultError,), on_retry=lambda i, e: print(f"[serve] retry {i + 1}: {e}"),
             rng=np.random.default_rng(spec.seed))
         # surface the winning attempt's degradation fields in the summary
         # (earlier versions printed them mid-flight and then dropped them)
@@ -276,6 +279,7 @@ def main():
                      help="full-run retries when the fault pre-flight "
                           "degrades (with --fault-spec)")
     args = ap.parse_args()
+    compile_cache.enable()
 
     if args.list_engines:
         for name, spec in sorted(sort_engine.engines().items()):

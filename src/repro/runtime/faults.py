@@ -48,6 +48,16 @@ import numpy as np
 from repro.core import bitplane as bp
 
 
+class FaultError(RuntimeError):
+    """An error of the simulated array: every bank dead, or a result that
+    failed verification.  Retry and fail-over paths catch this class only;
+    an error of the program or of the device it runs on propagates."""
+
+
+class VerificationError(FaultError):
+    """A sort result failed its comparison-free verification."""
+
+
 @dataclasses.dataclass(frozen=True)
 class FaultSpec:
     """One array's fault processes + the repair/retry policy knobs the

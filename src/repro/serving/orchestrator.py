@@ -33,6 +33,7 @@ from typing import Callable, Dict, List, Optional, Sequence
 
 import numpy as np
 
+from repro.runtime.faults import FaultError
 from repro.serving.clock import SimulatedClock
 from repro.serving.dispatch import Dispatcher
 from repro.serving.metrics import ServeStats, TickStats
@@ -257,7 +258,9 @@ class Orchestrator:
                     x, engine=engine, k=self.cfg.lifo_k,
                     ascending=members[0].ascending,
                     stop_after=None if stop >= n else stop)
-            except Exception:
+            except FaultError:
+                # the array failed (dead banks, unverifiable result); an
+                # error of the program or the chip propagates
                 self._step_retries += 1
                 self._cooldown["run"] = self.cfg.cooldown_ticks
                 if self._step_retries > self.cfg.max_step_retries:

@@ -12,12 +12,12 @@ the facade, the parity tests and the benchmark sweeps.
 """
 from repro.sort.api import (TOPK_ENGINES, engines, prune_mask, sort, topk,
                             topk_mask)
-from repro.sort.registry import (EngineSpec, available_engines, get_engine,
-                                 register)
+from repro.sort.registry import (EngineSpec, EngineUnsupported,
+                                 available_engines, get_engine, register)
 from repro.sort.result import SortResult
 
 __all__ = [
-    "EngineSpec", "SortResult", "TOPK_ENGINES", "available_engines",
+    "EngineSpec", "EngineUnsupported", "SortResult", "TOPK_ENGINES", "available_engines",
     "engines", "get_engine", "prune_mask", "register", "sort", "topk",
     "topk_mask",
 ]

@@ -18,7 +18,7 @@ from repro.core import catns
 from repro.core import radix_select as rs
 from repro.core import ref_tns as rt
 from repro.core import tns as jt
-from repro.sort.registry import register
+from repro.sort.registry import EngineUnsupported, register
 from repro.sort.result import SortResult
 
 
@@ -136,7 +136,7 @@ def _bts(x, *, width, fmt, k, ascending, level_bits, stop_after, **kw):
 def _bitslice(x, *, width, fmt, k, ascending, level_bits, stop_after,
               slice_widths=None, **kw):
     if not ascending:
-        raise NotImplementedError("bitslice oracle models ascending sorts")
+        raise EngineUnsupported("bitslice oracle models ascending sorts")
     if slice_widths is None:
         slice_widths = [width // 2, width - width // 2]
     out = rt.bitslice_sort(x, width=width, k=max(k, 1),
@@ -187,7 +187,7 @@ def _pallas_topk(x, *, width, fmt, k, ascending, level_bits, stop_after,
     if m > 32:
         # the kernel unrolls m min-searches in registers — a top-m engine,
         # not a full sorter (the router hot path is m <= 8)
-        raise NotImplementedError(
+        raise EngineUnsupported(
             f"pallas-topk extracts at most 32 minima per call (asked {m}); "
             "use stop_after, or the 'radix' engine for full sorts")
     kb = jnp.asarray(keys)
@@ -218,7 +218,7 @@ def _topk_keys_dispatch(keys: jnp.ndarray, m: int):
 def _pallas_tns(x, *, width, fmt, k, ascending, level_bits, stop_after,
                 block_rows=None, unroll=None, **kw):
     if level_bits != 1:
-        raise NotImplementedError(
+        raise EngineUnsupported(
             "pallas-tns runs binary (level_bits=1) planes; multi-level "
             "stays on the 'ml' while_loop machine")
     from repro.kernels import autotune, fused_tns
@@ -228,11 +228,11 @@ def _pallas_tns(x, *, width, fmt, k, ascending, level_bits, stop_after,
         xb = xb[None]
     b, n = xb.shape
     if n >= (1 << 15):
-        raise NotImplementedError(
+        raise EngineUnsupported(
             "pallas-tns supports N < 32768 per bank (same packed-count "
             "bound as the batched machine its oracle path reuses)")
     if width > 30:
-        raise NotImplementedError(
+        raise EngineUnsupported(
             "pallas-tns packs a lane's digit column into one int32 key; "
             "width <= 30 required (32-bit data stays on the while_loop "
             "machines)")

@@ -29,6 +29,12 @@ from repro.core import bitplane as bp
 ALL_FORMATS = (bp.UNSIGNED, bp.TWOS, bp.SIGNMAG, bp.FLOAT)
 
 
+class EngineUnsupported(NotImplementedError):
+    """An engine refuses an input outside what it was built for (a top-m
+    cap, a key width, a level count).  Callers that sweep engines skip on
+    this class only: a compiler or device error is never a refusal."""
+
+
 @dataclasses.dataclass(frozen=True)
 class EngineSpec:
     name: str

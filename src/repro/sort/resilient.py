@@ -160,16 +160,18 @@ def _repair_ladder(run: Callable[[faults.FaultSpec], SortResult],
         res = run(final_spec)
         if not check(res):
             attempts.append(res)
-            raise RuntimeError("resilient sort: verification failed")
+            raise faults.VerificationError(
+                "resilient sort: verification failed")
         return res
 
     try:
         res = run_step_with_retries(once, retries=base.max_retries,
                                     backoff_s=0.002, jitter=0.5,
+                                    retry_on=(faults.VerificationError,),
                                     rng=np.random.default_rng(base.seed))
         repairs = int(remapped) + 2
         return res, 1.0, repairs, retries, False, _burned_cycles(attempts)
-    except RuntimeError:
+    except faults.VerificationError:
         best = max(attempts, key=qual)
         rest = [a for a in attempts if a is not best]
         return best, qual(best), int(remapped), retries, True, \
@@ -297,7 +299,7 @@ def _mb_ft(x, *, width, fmt, k, ascending, level_bits, stop_after, banks=4,
         dead = faults.probe_dead_banks(spec, banks=banks)
     surviving = banks - len(dead)
     if surviving <= 0:
-        raise RuntimeError(f"mb-ft: all {banks} banks dead")
+        raise faults.FaultError(f"mb-ft: all {banks} banks dead")
     migrated, migration_cycles = (
         _migration_cost(n, banks, dead, width) if dead else (0, 0))
     base = spec.without_dead_banks() if (spec and dead) else spec

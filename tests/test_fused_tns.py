@@ -181,5 +181,7 @@ class TestEngineIntegration:
 
     def test_env_stamp_fields(self):
         stamp = backend.env_stamp()
-        assert set(stamp) == {"backend", "jax_version", "pallas_mode"}
+        assert set(stamp) == {"backend", "device_kind", "device_count",
+                              "jax_version", "pallas_mode"}
         assert stamp["pallas_mode"] in ("compiled", "interpret", "jnp")
+        assert stamp["device_count"] >= 1 and stamp["device_kind"]

@@ -48,8 +48,8 @@ from jax.sharding import PartitionSpec as P
 from repro import configs
 from repro.launch import mesh as mesh_lib, sharding as sh
 from repro.models import stacked
-from repro import compat
-mesh = compat.make_mesh((2, 4), ("data", "model"))
+mesh = jax.make_mesh((2, 4), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 cfg = configs.get_config("qwen3_14b")
 sds = jax.eval_shape(lambda k: stacked.init_params(cfg, k),
                      jax.random.PRNGKey(0))
@@ -83,8 +83,8 @@ from jax.sharding import PartitionSpec as P
 from repro import configs
 from repro.launch import sharding as sh
 from repro.models import stacked
-from repro import compat
-mesh = compat.make_mesh((1, 7), ("data", "model"))
+mesh = jax.make_mesh((1, 7), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 cfg = configs.get_config("qwen2_moe_a2_7b")   # 60 experts % 7 != 0
 sds = jax.eval_shape(lambda k: stacked.init_params(cfg, k),
                      jax.random.PRNGKey(0))
@@ -126,8 +126,8 @@ toks = jnp.asarray(np.random.default_rng(0).integers(0, cfg.vocab, (8, 16)),
 step = steps_lib.make_train_step(cfg, ocfg)
 p_ref, _, m_ref = jax.jit(step)(params, opt, toks, toks)
 
-from repro import compat
-mesh = compat.make_mesh((4, 2), ("data", "model"))
+mesh = jax.make_mesh((4, 2), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 pspecs, ospecs = sh.param_specs(mesh, params), sh.opt_specs(mesh, opt)
 with mesh:
     with shard.mesh_axes(("data",), "model"):

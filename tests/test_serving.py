@@ -268,7 +268,7 @@ class TestOrchestrator:
     def test_step_failure_cooldown_then_fail(self, monkeypatch):
         import repro.sort as sort_mod
         def boom(*a, **kw):
-            raise RuntimeError("injected step failure")
+            raise faults.FaultError("injected step failure")
         monkeypatch.setattr(sort_mod, "sort", boom)
         orch = serving.Orchestrator(
             clock=serving.SimulatedClock(),
@@ -284,6 +284,20 @@ class TestOrchestrator:
         orch.tick()                     # retry > max_step_retries: cohort fails
         assert req.status is Status.FAILED
         assert orch.stats.failed == 1 and not orch.batch
+
+    def test_device_error_propagates(self, monkeypatch):
+        # an error of the program or the chip (XLA's JaxRuntimeError is a
+        # RuntimeError) is never turned into a FAILED request
+        import repro.sort as sort_mod
+        def boom(*a, **kw):
+            raise RuntimeError("device error")
+        monkeypatch.setattr(sort_mod, "sort", boom)
+        orch = serving.Orchestrator(clock=serving.SimulatedClock())
+        req = _req(rid=0)
+        orch.submit(req)
+        with pytest.raises(RuntimeError, match="device error"):
+            orch.tick()
+        assert orch.stats.failed == 0 and not orch._inflight
 
     def test_backpressure_counts_rejections(self):
         clock = serving.SimulatedClock()

@@ -40,7 +40,7 @@ def _all_engine_perms(x, fmt, width, *, ascending=True, stop_after=None,
         try:
             res = S.sort(x, engine=name, fmt=fmt, width=width, k=k,
                          ascending=ascending, stop_after=stop_after)
-        except NotImplementedError:
+        except S.EngineUnsupported:
             continue
         perms[name] = np.asarray(res.indices)
     return perms

@@ -73,6 +73,8 @@ def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--out", default="BENCH_batched_tns.json")
     args = ap.parse_args()
+    from repro.launch import compile_cache
+    compile_cache.enable()
     result = measure()
     with open(args.out, "w") as f:
         json.dump(result, f, indent=2, sort_keys=True)
