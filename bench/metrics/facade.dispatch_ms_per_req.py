@@ -1,0 +1,10 @@
+"""Mean self time per traced call, in ms, of the program's ``sort.dispatch``
+spans: calls of jitted functions (the kernel launch, the counter-column
+slices, the radix sort): enqueue, not device time.  Layer: facade and
+engines."""
+from bench import program_spans as ps
+
+
+def read(run):
+    p = ps.program(run)
+    return None if p is None else ps.span_ms_per_call(p, "sort.dispatch")

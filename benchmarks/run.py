@@ -193,8 +193,8 @@ def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default=None,
                     help="comma-separated section filter "
-                         "(sort,apps,sweeps,kernels,pallas,roofline,"
-                         "resilience,serve)")
+                         "(sort,apps,sweeps,kernels,pallas,resilience,"
+                         "serve)")
     ap.add_argument("--smoke", action="store_true",
                     help="fast engine-registry pass for CI")
     ap.add_argument("--smoke-faults", action="store_true",
@@ -218,15 +218,14 @@ def main() -> None:
         sys.exit(smoke_pallas())
 
     from benchmarks import (bench_apps, bench_kernels, bench_pallas_tns,
-                            bench_resilience, bench_roofline, bench_serve,
-                            bench_sort, bench_sweeps)
+                            bench_resilience, bench_serve, bench_sort,
+                            bench_sweeps)
     sections = {
         "sort": bench_sort.run,          # Fig 4f-g, S18/S19, Table S5
         "apps": bench_apps.run,          # Fig 5, Fig 6, Fig S28
         "sweeps": bench_sweeps.run,      # S11, S12, Fig 2e-g
         "kernels": bench_kernels.run,    # kernel micro-benchmarks
         "pallas": bench_pallas_tns.run,  # fused TNS vs machine vs XLA
-        "roofline": bench_roofline.run,  # §Roofline table from dry-run
         "resilience": bench_resilience.run,  # Fig. S28 + §2.3.1 faults
         "serve": bench_serve.run,        # continuous batching vs one-shot
     }

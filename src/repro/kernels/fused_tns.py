@@ -72,6 +72,7 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.core import bitplane as bp
 from repro.kernels import backend
 from repro.kernels.digit_read import pack_columns, pad_lanes, pad_to
+from repro.runtime import spans
 
 
 class FusedOut(NamedTuple):
@@ -411,11 +412,14 @@ def fused_tns_sort(values, *, width: int, k: int, fmt: str = bp.UNSIGNED,
             "multi-level stays on the while_loop machine")
     x = np.asarray(values)
     assert x.ndim == 2, "fused_tns_sort expects a (B, N) batch"
-    digits = bp.to_bitplanes(x, width, fmt)
-    digits = bp.read_planes(digits, kind="bit", level_bits=1)
-    sign = None
-    if fmt in (bp.SIGNMAG, bp.FLOAT):
-        sign = jnp.asarray(bp.sign_plane(x, width, fmt))
+    with spans.span("sort.encode"):
+        digits = bp.to_bitplanes(x, width, fmt)
+        digits = bp.read_planes(digits, kind="bit", level_bits=1)
+        sign = None
+        if fmt in (bp.SIGNMAG, bp.FLOAT):
+            sign = bp.sign_plane(x, width, fmt)
+    if sign is not None:
+        sign = spans.to_device(sign)
     if backend.use_ref(None):
         from repro.core import tns as jt
         out = jt.tns_sort_planes_batched(
@@ -424,9 +428,15 @@ def fused_tns_sort(values, *, width: int, k: int, fmt: str = bp.UNSIGNED,
         # the machine has no mixed-read counter; drs upper-bounds it
         return FusedOut(out.perm, out.cycles, out.drs, out.reload_cycles,
                         out.drs)
-    rank, cnt = _fused_tns_rank(jnp.asarray(digits), sign, k=k, fmt=fmt,
-                                ascending=ascending, stop_after=stop_after,
-                                block_rows=block_rows, unroll=unroll)
-    perm = _rank_to_perm_np(np.asarray(rank))
-    return FusedOut(perm, cnt[:, _CYC], cnt[:, _DRS], cnt[:, _RLC],
-                    cnt[:, _UDR])
+    planes = spans.to_device(digits)
+    with spans.span("sort.dispatch"):
+        rank, cnt = _fused_tns_rank(planes, sign, k=k, fmt=fmt,
+                                    ascending=ascending,
+                                    stop_after=stop_after,
+                                    block_rows=block_rows, unroll=unroll)
+    rank = spans.to_host(rank)
+    with spans.span("sort.rank_to_perm"):
+        perm = _rank_to_perm_np(rank)
+    with spans.span("sort.dispatch"):
+        return FusedOut(perm, cnt[:, _CYC], cnt[:, _DRS], cnt[:, _RLC],
+                        cnt[:, _UDR])

@@ -18,6 +18,7 @@ from repro.core import catns
 from repro.core import radix_select as rs
 from repro.core import ref_tns as rt
 from repro.core import tns as jt
+from repro.runtime import spans
 from repro.sort.registry import EngineUnsupported, register
 from repro.sort.result import SortResult
 
@@ -25,15 +26,16 @@ from repro.sort.result import SortResult
 def _finish(x, perm, *, engine, fmt, width, k=0, level_bits=1,
             stop_after=None, cycles=None, drs=None, reload_cycles=None,
             strategy=None) -> SortResult:
-    perm = np.asarray(perm)
-    if stop_after is not None:
-        perm = perm[..., :stop_after]
-    vals = np.take_along_axis(np.asarray(x), perm, axis=-1)
-    asarr = lambda v: None if v is None else np.asarray(v)
-    return SortResult(values=vals, indices=perm, engine=engine, fmt=fmt,
-                      width=width, n=x.shape[-1], cycles=asarr(cycles),
-                      drs=asarr(drs), reload_cycles=asarr(reload_cycles),
-                      strategy=strategy, k=k, level_bits=level_bits)
+    perm, cycles, drs, reload_cycles = map(
+        spans.to_host, (perm, cycles, drs, reload_cycles))
+    with spans.span("sort.finish"):
+        if stop_after is not None:
+            perm = perm[..., :stop_after]
+        vals = np.take_along_axis(np.asarray(x), perm, axis=-1)
+        return SortResult(values=vals, indices=perm, engine=engine, fmt=fmt,
+                          width=width, n=x.shape[-1], cycles=cycles, drs=drs,
+                          reload_cycles=reload_cycles, strategy=strategy,
+                          k=k, level_bits=level_bits)
 
 
 # ---------------------------------------------------------------------------
@@ -155,12 +157,13 @@ def _bitslice(x, *, width, fmt, k, ascending, level_bits, stop_after,
 
 
 def _unsigned_keys(x, width, fmt, ascending) -> np.ndarray:
-    keys = bp.sort_key(x, width, fmt)
-    if not ascending:
-        dt = keys.dtype
-        keys = (((~keys.astype(np.uint64)) & np.uint64((1 << width) - 1))
-                .astype(dt))
-    return keys
+    with spans.span("sort.encode"):
+        keys = bp.sort_key(x, width, fmt)
+        if not ascending:
+            dt = keys.dtype
+            keys = (((~keys.astype(np.uint64)) & np.uint64((1 << width) - 1))
+                    .astype(dt))
+        return keys
 
 
 @register("radix", mode="throughput", supports_stop_after=True,
@@ -171,7 +174,9 @@ def _radix(x, *, width, fmt, k, ascending, level_bits, stop_after,
            r=None, **kw):
     keys = _unsigned_keys(x, width, fmt, ascending)
     rr = r or (8 if width % 8 == 0 else 4)
-    perm = rs.radix_sort_keys(jnp.asarray(keys), r=rr)
+    keys = spans.to_device(keys)
+    with spans.span("sort.dispatch"):
+        perm = rs.radix_sort_keys(keys, r=rr)
     return _finish(x, perm, engine="radix", fmt=fmt, width=width,
                    stop_after=stop_after)
 
@@ -239,15 +244,14 @@ def _pallas_tns(x, *, width, fmt, k, ascending, level_bits, stop_after,
     m = n if stop_after is None else min(stop_after, n)
     if block_rows is None and unroll is None:
         # the committed autotune table picks the grid shape per cell
-        params = autotune.best_params(fmt, n, m, b)
+        with spans.span("sort.params"):
+            params = autotune.best_params(fmt, n, m, b)
         block_rows = params["block_rows"] or None
         unroll = params["unroll"]
     out = fused_tns.fused_tns_sort(
         xb, width=width, k=k, fmt=fmt, ascending=ascending,
         stop_after=stop_after, block_rows=block_rows, unroll=unroll or 1)
-    perm, cycles, drs, rlc = (np.asarray(out.perm), np.asarray(out.cycles),
-                              np.asarray(out.drs),
-                              np.asarray(out.reload_cycles))
+    perm, cycles, drs, rlc = map(spans.to_host, out[:4])
     if squeeze:
         perm, cycles, drs, rlc = perm[0], cycles[0], drs[0], rlc[0]
     return _finish(x, perm, engine="pallas-tns", fmt=fmt, width=width,
