@@ -58,6 +58,13 @@ def raw_bits(x, width: int, fmt: str) -> np.ndarray:
     if fmt not in _FORMATS:
         raise ValueError(f"unknown format {fmt!r}")
     x = np.asarray(x)
+    if fmt in (UNSIGNED, TWOS) and x.dtype.kind in "ui":
+        # integer casts wrap modulo the container's 2**bits, so its low
+        # ``width`` bits are those of the 64-bit pattern below, without
+        # the 8-byte temporaries
+        u = x.astype(_container(width))
+        u &= u.dtype.type((1 << width) - 1)
+        return u
     if fmt == UNSIGNED:
         u = x.astype(np.uint64) & _mask(width)
     elif fmt == TWOS:
@@ -82,9 +89,14 @@ def to_bitplanes(x, width: int, fmt: str) -> np.ndarray:
     matrix.  Row 0 = MSB (the first column the paper's DR visits).  Leading
     dims are independent datasets (one memristor bank each)."""
     u = raw_bits(x, width, fmt)      # container dtype: 4-8x less traffic
-    shifts = np.arange(width - 1, -1, -1, dtype=u.dtype)
-    return ((u[..., None, :] >> shifts[:, None])
-            & u.dtype.type(1)).astype(np.uint8)
+    # one plane at a time into the output: no (..., W, N) temporaries,
+    # whose pages a call would otherwise fault in afresh
+    planes = np.empty(u.shape[:-1] + (width, u.shape[-1]), np.uint8)
+    for c in range(width):
+        row = planes[..., c, :]
+        np.right_shift(u, width - 1 - c, out=row, casting="unsafe")
+        row &= 1
+    return planes
 
 
 def to_digitplanes(x, width: int, fmt: str, level_bits: int) -> np.ndarray:

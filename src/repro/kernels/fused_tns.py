@@ -49,10 +49,14 @@ cycles — is asserted in tests/test_fused_tns.py):
 * Every running episode emits at least one number, so ``stop_after``
   emissions need at most ``stop_after`` episodes — the static trip count.
 
-Outputs are an inverse-permutation ring (rank[i] = emission slot of
-element i) plus per-instance counters; the wrapper scatters rank into the
-forward permutation.  ``level_bits > 1`` stays on the while_loop machine
-(EngineUnsupported here, same restriction as the packed fast path).
+The kernel writes an inverse-permutation ring (rank[i] = emission slot
+of element i) plus per-instance counters.  The jit around it packs what
+the host needs into ONE int32 array, read back once: the four counter
+lanes, then, for ``stop_after`` <= ``DEVICE_PERM_MAX``, the permutation's
+first slots (a compare and max-reduce over the ring on the device), and
+otherwise the whole ring, which the host inverts with numpy.
+``level_bits > 1`` stays on the while_loop machine (EngineUnsupported
+here, same restriction as the packed fast path).
 
 Dispatch: compiled on TPU/GPU, ``interpret`` on CPU, and under
 ``REPRO_PALLAS=jnp`` the oracle path reuses ``tns_sort_planes_batched``
@@ -76,17 +80,25 @@ from repro.runtime import spans
 
 
 class FusedOut(NamedTuple):
-    perm: jnp.ndarray           # (B, N) int32 emission order (-1 pad)
+    perm: jnp.ndarray           # (B, stop_n) int32 emission order ((B, N),
+                                # -1 pad, from fused_tns_planes)
     cycles: jnp.ndarray         # (B,) int32 controller cycles
     drs: jnp.ndarray            # (B,) int32 digit reads (all)
     reload_cycles: jnp.ndarray  # (B,) int32 redundant reload cycles
     useful_drs: jnp.ndarray     # (B,) int32 mixed reads (caused exclusion)
 
 
-# counter columns written by the kernel
+# counter columns written by the kernel; the first _NOUT lead the packed
+# output of _fused_tns_rank
 _CYC, _DRS, _RLC, _UDR, _OUT = range(5)
+_NOUT = 4
 _NCNT = 8          # counter block padded to 8 lanes
 _SIGN_BIT = 30     # sign plane's bit in a lane's packed word
+# Largest stop_n whose permutation slots are found on the device (the
+# top-m regime: serving's m range and pallas-topk's limit).  The search
+# compares every lane with every slot, B * stop_n * N work; above this the
+# ring goes to the host, where one scatter inverts it.
+DEVICE_PERM_MAX = 32
 
 
 def _flip_mask(fmt: str, ascending: bool, width: int, neg_pend):
@@ -313,25 +325,20 @@ def _block_rows(block_rows: Optional[int], b: int) -> int:
     return min(b, -(-max(1, block_rows) // 8) * 8)
 
 
-@functools.partial(
-    jax.jit,
-    static_argnames=("k", "fmt", "ascending", "stop_after", "block_rows",
-                     "unroll", "interpret"))
-def _fused_tns_rank(planes: jnp.ndarray,
-                    sign_bits: Optional[jnp.ndarray] = None,
-                    *, k: int, fmt: str = bp.UNSIGNED,
-                    ascending: bool = True,
-                    stop_after: Optional[int] = None,
-                    block_rows: Optional[int] = None, unroll: int = 1,
-                    interpret: bool | None = None):
-    """Kernel launch returning the raw (rank ring, counter block); rank[i]
-    is element i's emission slot, -1 if never emitted."""
+def _stop_n(n: int, stop_after: Optional[int]) -> int:
+    """Emissions the kernel makes: ``stop_after`` capped at N, at least 1."""
+    return max(n if stop_after is None else min(stop_after, n), 1)
+
+
+def _launch(planes, sign_bits, *, k, fmt, ascending, stop_after,
+            block_rows, unroll, interpret):
+    """The ``pallas_call``: (rank ring (B, N), counter block (B, _NCNT));
+    rank[i] is element i's emission slot, -1 if never emitted."""
     interpret = backend.use_interpret(interpret)
     assert planes.ndim == 3, "fused_tns_planes expects (B, W, N) planes"
     assert planes.shape[1] <= _SIGN_BIT, "digit keys are packed into int32"
     B, W, N = planes.shape
-    stop_n = N if stop_after is None else min(stop_after, N)
-    stop_n = max(stop_n, 1)
+    stop_n = _stop_n(N, stop_after)
     Np = pad_lanes(N)
     bm = _block_rows(block_rows, B)
     b_pad = -(-B // bm) * bm
@@ -355,8 +362,41 @@ def _fused_tns_rank(planes: jnp.ndarray,
     return rank[:B, :N], cnt[:B]
 
 
+@functools.partial(
+    jax.jit,
+    static_argnames=("k", "fmt", "ascending", "stop_after", "block_rows",
+                     "unroll", "interpret"))
+def _fused_tns_rank(planes: jnp.ndarray,
+                    sign_bits: Optional[jnp.ndarray] = None,
+                    *, k: int, fmt: str = bp.UNSIGNED,
+                    ascending: bool = True,
+                    stop_after: Optional[int] = None,
+                    block_rows: Optional[int] = None, unroll: int = 1,
+                    interpret: bool | None = None):
+    """Kernel launch and its epilogue, as one (B, _NOUT + S) int32 array:
+    the counter lanes (cycles, DRs, reload cycles, useful DRs), then, for
+    stop_n <= DEVICE_PERM_MAX, the permutation's first S = stop_n slots,
+    else the S = N rank ring for the host to invert."""
+    rank, cnt = _launch(planes, sign_bits, k=k, fmt=fmt,
+                        ascending=ascending, stop_after=stop_after,
+                        block_rows=block_rows, unroll=unroll,
+                        interpret=interpret)
+    B, N = rank.shape
+    stop_n = _stop_n(N, stop_after)
+    body = rank
+    if stop_n <= DEVICE_PERM_MAX:
+        # slot j holds the one lane ranked j: emitted ranks are unique and
+        # slots below stop_n all filled (-1, as on the host, if not)
+        lane = jax.lax.broadcasted_iota(jnp.int32, (1, 1, N), 2)
+        slot = jax.lax.broadcasted_iota(jnp.int32, (1, stop_n, 1), 1)
+        body = jnp.max(jnp.where(rank[:, None, :] == slot, lane, -1),
+                       axis=2)
+    return jnp.concatenate([cnt[:, :_NOUT], body], axis=1)
+
+
 def _rank_to_perm_np(rank: np.ndarray) -> np.ndarray:
-    """Invert the rank ring on the host: XLA:CPU lowers the equivalent
+    """Invert the rank ring on the host, for stop_n > DEVICE_PERM_MAX
+    (below it the device finds the slots): XLA:CPU lowers the equivalent
     scatter to a scalar loop (~3.6ms for 64x1024), numpy fancy indexing
     does it in ~0.1ms — this is on the serving path, so it matters."""
     B, N = rank.shape
@@ -383,7 +423,7 @@ def fused_tns_planes(planes: jnp.ndarray,
     reload counts match :func:`repro.core.tns.tns_sort_planes` exactly;
     ``useful_drs`` additionally counts only the mixed reads.
     ``interpret=None`` resolves per backend."""
-    rank, cnt = _fused_tns_rank(
+    rank, cnt = _launch(
         planes, sign_bits, k=k, fmt=fmt, ascending=ascending,
         stop_after=stop_after, block_rows=block_rows, unroll=unroll,
         interpret=interpret)
@@ -420,23 +460,26 @@ def fused_tns_sort(values, *, width: int, k: int, fmt: str = bp.UNSIGNED,
             sign = bp.sign_plane(x, width, fmt)
     if sign is not None:
         sign = spans.to_device(sign)
+    stop_n = _stop_n(x.shape[1], stop_after)
     if backend.use_ref(None):
         from repro.core import tns as jt
         out = jt.tns_sort_planes_batched(
             jnp.asarray(digits.astype(np.int32)), sign, k=k, fmt=fmt,
             ascending=ascending, stop_after=stop_after)
+        perm, cycles, drs, rlc = map(spans.to_host, out[:4])
         # the machine has no mixed-read counter; drs upper-bounds it
-        return FusedOut(out.perm, out.cycles, out.drs, out.reload_cycles,
-                        out.drs)
+        return FusedOut(perm[:, :stop_n], cycles, drs, rlc, drs)
     planes = spans.to_device(digits)
     with spans.span("sort.dispatch"):
-        rank, cnt = _fused_tns_rank(planes, sign, k=k, fmt=fmt,
-                                    ascending=ascending,
-                                    stop_after=stop_after,
-                                    block_rows=block_rows, unroll=unroll)
-    rank = spans.to_host(rank)
-    with spans.span("sort.rank_to_perm"):
-        perm = _rank_to_perm_np(rank)
-    with spans.span("sort.dispatch"):
-        return FusedOut(perm, cnt[:, _CYC], cnt[:, _DRS], cnt[:, _RLC],
-                        cnt[:, _UDR])
+        out = _fused_tns_rank(planes, sign, k=k, fmt=fmt,
+                              ascending=ascending, stop_after=stop_after,
+                              block_rows=block_rows, unroll=unroll)
+    out = spans.to_host(out)
+    on_device = stop_n <= DEVICE_PERM_MAX
+    spans.count("device_perm", int(on_device))
+    perm = out[:, _NOUT:]            # the slots, or the ring to invert
+    if not on_device:
+        with spans.span("sort.rank_to_perm"):
+            perm = _rank_to_perm_np(perm)[:, :stop_n]
+    return FusedOut(perm, out[:, _CYC], out[:, _DRS], out[:, _RLC],
+                    out[:, _UDR])

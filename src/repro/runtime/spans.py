@@ -21,7 +21,9 @@ The ``sort.*`` vocabulary (one root ``sort`` span per call):
 * ``sort.dispatch`` - calls of jitted functions (enqueue, not run time);
 * ``sort.readback`` - a device array read to the host, which waits for the
   device (counters ``readbacks``, ``d2h_bytes``);
-* ``sort.rank_to_perm`` - the rank ring inverted on the host;
+* ``sort.rank_to_perm`` - the rank ring inverted on the host (``pallas-tns``
+  past ``fused_tns.DEVICE_PERM_MAX`` emissions; below it the device finds
+  the permutation's slots, counted as ``device_perm``);
 * ``sort.finish`` - the result's slice, value gather and ``SortResult``.
 """
 from __future__ import annotations

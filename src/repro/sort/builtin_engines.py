@@ -251,7 +251,7 @@ def _pallas_tns(x, *, width, fmt, k, ascending, level_bits, stop_after,
     out = fused_tns.fused_tns_sort(
         xb, width=width, k=k, fmt=fmt, ascending=ascending,
         stop_after=stop_after, block_rows=block_rows, unroll=unroll or 1)
-    perm, cycles, drs, rlc = map(spans.to_host, out[:4])
+    perm, cycles, drs, rlc = out[:4]
     if squeeze:
         perm, cycles, drs, rlc = perm[0], cycles[0], drs[0], rlc[0]
     return _finish(x, perm, engine="pallas-tns", fmt=fmt, width=width,

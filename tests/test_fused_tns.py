@@ -7,6 +7,10 @@
   k=0, both directions).
 * Observables: the in-kernel useful-DR count matches the while_loop
   machine's mixed-read count.
+* Epilogue: the kernel's jit returns the counter lanes and, up to
+  ``DEVICE_PERM_MAX`` emissions, the permutation's slots found on the
+  device; both equal what the host reads off the raw rank ring and
+  counter block.
 * Autotune: the (block_rows, unroll) knobs never change results, and the
   table round-trips through save/load with mode-scoped nearest-cell
   lookup.
@@ -15,6 +19,8 @@
 """
 import json
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -105,6 +111,52 @@ class TestParity:
         out = fused_tns.fused_tns_sort(ties, width=8, k=2,
                                        fmt=bp.UNSIGNED)
         assert np.all(np.asarray(out.useful_drs) == 0)
+
+
+# the bare kernel launch: the rank ring and counter block the epilogue reads
+_raw = jax.jit(fused_tns._launch,
+               static_argnames=("k", "fmt", "ascending", "stop_after",
+                                "block_rows", "unroll", "interpret"))
+
+# (fmt, N, ascending); 130 is not a lane multiple, FLOAT has a sign plane
+EPILOGUE_INPUTS = {
+    "u8_n24": (bp.UNSIGNED, 24, True),
+    "u8_n130": (bp.UNSIGNED, 130, True),
+    "i8_n130_descending": (bp.TWOS, 130, False),
+    "f16_n130": (bp.FLOAT, 130, True),
+}
+
+
+class TestEpilogue:
+    @pytest.mark.parametrize("m", [1, 2, 6, 32, 33, None])
+    @pytest.mark.parametrize("case", list(EPILOGUE_INPUTS))
+    def test_device_slots_match_host_inversion(self, case, m):
+        fmt, n, ascending = EPILOGUE_INPUTS[case]
+        x, width = _batch(fmt, n, 3)
+        x[1] = x[1, 0]                  # all ties: slots in index order
+        planes = jnp.asarray(bp.to_bitplanes(x, width, fmt))
+        sign = (jnp.asarray(bp.sign_plane(x, width, fmt))
+                if fmt == bp.FLOAT else None)
+        kw = dict(k=2, fmt=fmt, ascending=ascending, stop_after=m,
+                  block_rows=None, unroll=1, interpret=None)
+        rank, cnt = map(np.asarray, _raw(planes, sign, **kw))
+        stop_n = n if m is None else min(m, n)
+        want = fused_tns._rank_to_perm_np(rank)[:, :stop_n]
+        out = np.asarray(fused_tns._fused_tns_rank(planes, sign, **kw))
+        np.testing.assert_array_equal(out[:, :fused_tns._NOUT],
+                                      cnt[:, :fused_tns._NOUT])
+        np.testing.assert_array_equal(
+            out[:, fused_tns._NOUT:],
+            want if stop_n <= fused_tns.DEVICE_PERM_MAX else rank)
+        got = fused_tns.fused_tns_sort(x, width=width, k=2, fmt=fmt,
+                                       ascending=ascending, stop_after=m)
+        np.testing.assert_array_equal(got.perm, want)
+        np.testing.assert_array_equal(got.perm[1], np.arange(stop_n))
+        for field, col in (("cycles", fused_tns._CYC),
+                           ("drs", fused_tns._DRS),
+                           ("reload_cycles", fused_tns._RLC),
+                           ("useful_drs", fused_tns._UDR)):
+            np.testing.assert_array_equal(getattr(got, field), cnt[:, col])
 
 
 class TestAutotune:

@@ -187,6 +187,44 @@ class TestFacade:
             registry._REGISTRY.pop("np-sort", None)
 
 
+# (fmt, width, data): integer inputs wider than the container, negative
+# keys read as unsigned, 1-D and (B, N) shapes, every container width
+_ENC = np.random.default_rng(12)
+ENCODINGS = {
+    "u8": (bp.UNSIGNED, 8, _ENC.integers(0, 256, (3, 40)).astype(np.uint8)),
+    "u8_from_int64": (bp.UNSIGNED, 8, _ENC.integers(-600, 600, 50)),
+    "u12_from_uint16": (bp.UNSIGNED, 12,
+                        _ENC.integers(0, 2**16, (2, 33)).astype(np.uint16)),
+    "u64": (bp.UNSIGNED, 64, _ENC.integers(0, 2**63, 20, dtype=np.uint64)
+            * np.uint64(2) + np.uint64(1)),
+    "twos8": (bp.TWOS, 8, _ENC.integers(-128, 128, (2, 30)).astype(np.int8)),
+    "twos5_from_int64": (bp.TWOS, 5, _ENC.integers(-40, 40, 30)),
+    "signmag16": (bp.SIGNMAG, 16, _ENC.integers(-2**14, 2**14, 30)),
+    "float16": (bp.FLOAT, 16, _ENC.standard_normal(30).astype(np.float16)),
+    "float32": (bp.FLOAT, 32,
+                _ENC.standard_normal((2, 20)).astype(np.float32)),
+}
+
+
+class TestEncoding:
+    @pytest.mark.parametrize("case", list(ENCODINGS))
+    def test_bitplanes_are_the_64_bit_pattern_msb_first(self, case):
+        fmt, width, x = ENCODINGS[case]
+        if fmt in (bp.UNSIGNED, bp.TWOS):
+            u = x.astype(np.int64).astype(np.uint64)
+            u &= np.uint64((1 << width) - 1) if width < 64 else ~np.uint64(0)
+            raw = bp.raw_bits(x, width, fmt)
+            assert raw.dtype == bp._container(width)
+            np.testing.assert_array_equal(raw.astype(np.uint64), u)
+        else:
+            u = bp.raw_bits(x, width, fmt).astype(np.uint64)
+        shifts = np.arange(width - 1, -1, -1, dtype=np.uint64)
+        want = ((u[..., None, :] >> shifts[:, None]) & np.uint64(1))
+        got = bp.to_bitplanes(x, width, fmt)
+        assert got.dtype == np.uint8 and got.shape == want.shape
+        np.testing.assert_array_equal(got, want.astype(np.uint8))
+
+
 class TestInModelDispatchers:
     def test_topk_engines_agree_with_lax(self):
         x = jnp.asarray(RNG.standard_normal((3, 5, 24)), jnp.float32)

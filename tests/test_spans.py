@@ -19,40 +19,48 @@ B, N = 4, 128
 X8 = np.random.default_rng(3).integers(0, 256, (B, N)).astype(np.uint8)
 X32 = np.random.default_rng(4).integers(0, 2**32, (B, N), dtype=np.uint32)
 
-# (engine, input, stop_after, child spans in order, counters)
+# case: (engine, input, stop_after, child spans in order, counters)
 CALLS = {
-    "pallas-tns": (X8, 1, ["sort.params", "sort.encode", "sort.h2d",
-                           "sort.dispatch", "sort.readback",
-                           "sort.rank_to_perm", "sort.dispatch",
-                           "sort.readback", "sort.readback",
-                           "sort.readback", "sort.finish"],
-                   # 8 uint8 planes in; the int32 rank ring and three int32
-                   # counter columns out, each read on its own
-                   {"h2d_bytes": B * 8 * N, "readbacks": 4,
-                    "d2h_bytes": B * N * 4 + 3 * B * 4}),
-    "radix": (X32, None, ["sort.encode", "sort.h2d", "sort.dispatch",
-                          "sort.readback", "sort.finish"],
+    "pallas-tns": ("pallas-tns", X8, 1,
+                   ["sort.params", "sort.encode", "sort.h2d",
+                    "sort.dispatch", "sort.readback", "sort.finish"],
+                   # 8 uint8 planes in; one int32 array out: the four
+                   # counter lanes and the permutation's first slot, found
+                   # on the device
+                   {"h2d_bytes": B * 8 * N, "readbacks": 1,
+                    "d2h_bytes": B * (4 + 1) * 4, "device_perm": 1}),
+    # past DEVICE_PERM_MAX emissions the rank ring comes back with the
+    # counter lanes, still in one readback, and the host inverts it
+    "pallas-tns-ring": ("pallas-tns", X8, 33,
+                        ["sort.params", "sort.encode", "sort.h2d",
+                         "sort.dispatch", "sort.readback",
+                         "sort.rank_to_perm", "sort.finish"],
+                        {"h2d_bytes": B * 8 * N, "readbacks": 1,
+                         "d2h_bytes": B * (4 + N) * 4, "device_perm": 0}),
+    "radix": ("radix", X32, None, ["sort.encode", "sort.h2d",
+                                   "sort.dispatch", "sort.readback",
+                                   "sort.finish"],
               {"h2d_bytes": B * N * 4, "readbacks": 1,
                "d2h_bytes": B * N * 4}),
 }
 
 
-def call(engine):
-    x, m, _, _ = CALLS[engine]
+def call(case):
+    engine, x, m, _, _ = CALLS[case]
     return S.sort(x, engine=engine, k=2, stop_after=m)
 
 
 @pytest.fixture(scope="module")
 def traced(tmp_path_factory):
-    """One call per engine under a profiler, after a warm-up call each
+    """One call per case under a profiler, after a warm-up call each
     with none running; (records kept by the warm-up, records of the traced
     calls, the trace's host event names, both calls' results)."""
     spans.clear()
-    plain = [call(engine) for engine in CALLS]
+    plain = [call(case) for case in CALLS]
     idle = spans.records()
     d = str(tmp_path_factory.mktemp("trace"))
     with jax.profiler.trace(d):
-        recorded = [call(engine) for engine in CALLS]
+        recorded = [call(case) for case in CALLS]
     recs = spans.records()
     spans.clear()
     (path,) = glob.glob(f"{d}/plugins/profile/*/*.xplane.pb")
@@ -67,15 +75,15 @@ def test_nothing_is_kept_without_a_profiler(traced):
     assert traced[0] == []
 
 
-@pytest.mark.parametrize("engine", list(CALLS))
-def test_one_root_per_call_with_children_and_counters(traced, engine):
+@pytest.mark.parametrize("case", list(CALLS))
+def test_one_root_per_call_with_children_and_counters(traced, case):
     recs = traced[1]
     roots = [i for i, r in enumerate(recs) if r.parent == -1]
-    assert [recs[i].name for i in roots] == ["sort", "sort"]
-    i = roots[list(CALLS).index(engine)]
+    assert [recs[i].name for i in roots] == ["sort"] * len(CALLS)
+    i = roots[list(CALLS).index(case)]
     root = recs[i]
     kids = [r for r in recs if r.call == root.call and r.parent != -1]
-    _, _, names, counts = CALLS[engine]
+    _, _, _, names, counts = CALLS[case]
     assert [r.name for r in kids] == names
     assert all(r.parent == i for r in kids)
     assert all(root.start_ns <= r.start_ns <= r.end_ns <= root.end_ns
@@ -86,7 +94,7 @@ def test_one_root_per_call_with_children_and_counters(traced, engine):
 
 def test_spans_show_on_the_trace_host_plane(traced):
     names = traced[2]
-    assert {n for _, _, ns, _ in CALLS.values() for n in ns} | {"sort"} \
+    assert {n for _, _, _, ns, _ in CALLS.values() for n in ns} | {"sort"} \
         <= names
 
 
