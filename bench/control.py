@@ -1,7 +1,8 @@
 #!/usr/bin/env python3
 """The control of a cell's comparison: the plain reference put in the
-place of ``sort()``, one guarantee broken (see the reference's
-``control_call``), driven through the cell's own window and check.  Its
+place of the cell's entry, one guarantee broken (see the reference's
+``control_call``), driven through the cell's own window and check (an
+open loop serves it through ``harness.Synchronous``).  Its
 compared numbers must exceed their limits; they are the upper readings the
 limits are set below.  The benchmark's own runs never run it.
 

@@ -5,14 +5,17 @@
                          --seconds <s> --trace <0|1>
 
 The cell is found by name in ``BENCHMARK.json``; its configuration,
-traffic mix, reference and metric readers are files under ``bench/``.
-The run sets up (JAX, the request pool from the seed, made on a second
-thread while JAX comes up, one call of each shape), measures for ``--seconds``, compares every answer of the window
-with the plain reference, and prints one JSON line as the last line of
-standard output: ``correct``, ``attempted``, ``failed``, ``metrics`` (the
-cell's end-to-end metrics, or with ``--trace 1`` its per-layer metrics
-from a profiler trace of the window), ``device``, with ``--trace 1``
-``breakdown``, and last ``checks``, each compared number with its limit.
+traffic mix, entry, generator, reference and metric readers are files
+under ``bench/`` (``bench/spec.py``).  The run sets up (JAX, the request
+pool from the seed, made on a second thread while JAX comes up, each
+shape once through the entry), measures for ``--seconds`` in the cell's
+closed or open loop (``bench/harness.py``), compares every answer of the
+window with the plain reference, and prints one JSON line as the last
+line of standard output: ``correct``, ``attempted``, ``failed``,
+``metrics`` (the cell's end-to-end metrics, or with ``--trace 1`` its
+per-layer metrics from a profiler trace of the window), ``device``, with
+``--trace 1`` ``breakdown``, and last ``checks``, each compared number
+with its limit.
 The same numbers are the last lines of standard error.
 
 It runs only on a TPU, with the Pallas kernels compiled: it exits with a
@@ -67,7 +70,8 @@ def main(argv=None) -> int:
     from bench.spec import load_cell
     from bench.traffic.generate import pool_in_background
     cell = load_cell(args.workload)
-    pool = pool_in_background(cell.cfg, cell.traffic, args.seed)
+    pool = pool_in_background(cell.cfg, cell.traffic, args.seed,
+                              cell.generator.make_pool)
     import jax
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
     devices = jax.devices()

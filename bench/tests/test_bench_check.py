@@ -8,7 +8,8 @@ import time
 import numpy as np
 import pytest
 
-from bench.harness import measure, sort_call
+from bench.entries.sort import make as sort_call
+from bench.harness import measure
 from bench.references import sort_reference as ref
 from bench.spec import load_cell
 from bench.traffic.datasets import DATASETS, make_dataset

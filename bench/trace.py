@@ -3,9 +3,10 @@
 The traced run records, on one clock:
 
 * host spans that the harness writes with ``jax.profiler.TraceAnnotation``:
-  ``window`` around the measured window, ``sort_call`` around each call of
-  ``sort()``, ``between_calls`` around the harness's own work between two
-  calls;
+  ``window`` around the measured window; in a closed loop ``sort_call``
+  around each call of ``sort()`` and ``between_calls`` around the
+  harness's own work between two calls; in an open loop ``submit``,
+  ``serve_step`` and ``serve_wait`` (``bench/harness.py``);
 * device events on each ``/device:TPU:<i>`` plane: the ``XLA Modules``
   line (one event per executable run, named ``jit_<function>(<id>)``) and
   the ``XLA Ops`` line (one event per operation, named by its HLO text; a
@@ -23,7 +24,8 @@ import re
 from collections import defaultdict
 from typing import NamedTuple
 
-HOST_SPANS = ("window", "sort_call", "between_calls")
+HOST_SPANS = ("window", "sort_call", "between_calls", "submit", "serve_step",
+              "serve_wait")
 DEVICE_PLANE = re.compile(r"^/device:TPU:\d+$")
 OPS_LINE, MODULES_LINE = "XLA Ops", "XLA Modules"
 
@@ -36,7 +38,7 @@ class Event(NamedTuple):
 
 class Trace(NamedTuple):
     window: Event             # the harness's window span
-    spans: list[Event]        # sort_call / between_calls spans in the window
+    spans: list[Event]        # the harness's other spans in the window
     ops: list[list[Event]]    # per device: operations, clipped, by start
     modules: list[list[Event]]  # per device: executables, clipped, by start
 

@@ -1,4 +1,6 @@
-"""The one generator of request pools, driven by a traffic mix's data file.
+"""The generator of request pools for closed-loop traffic, driven by a
+traffic mix's data file; a traffic file that names no ``generator`` uses
+it.
 
 A traffic file (``bench/traffic/<name>.json``) holds:
 
@@ -55,22 +57,23 @@ def make_pool(cfg: dict, traffic: dict, seed: int) -> list[Request]:
     return pool
 
 
-def pool_in_background(cfg: dict, traffic: dict,
-                       seed: int) -> Callable[[], list[Request]]:
-    """Start making the pool on a second thread, so that it overlaps JAX
-    and the chip coming up (numpy's generators release the GIL while they
-    fill arrays).  Returns a function that waits for the pool."""
+def pool_in_background(cfg: dict, traffic: dict, seed: int,
+                       make: Callable = make_pool) -> Callable[[], list]:
+    """Start ``make(cfg, traffic, seed)``, the cell's generator, on a
+    second thread, so that it overlaps JAX and the chip coming up (numpy's
+    generators release the GIL while they fill arrays).  Returns a
+    function that waits for the pool."""
     box: dict = {}
 
     def work():
         try:
-            box["pool"] = make_pool(cfg, traffic, seed)
+            box["pool"] = make(cfg, traffic, seed)
         except BaseException as e:      # re-raised on the caller's thread
             box["error"] = e
     thread = threading.Thread(target=work, name="make_pool", daemon=True)
     thread.start()
 
-    def result() -> list[Request]:
+    def result() -> list:
         thread.join()
         if "error" in box:
             raise box["error"]
