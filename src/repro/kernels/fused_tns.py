@@ -74,6 +74,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import bitplane as bp
+from repro.core.radix_select import exclusive_prefix
 from repro.kernels import backend
 from repro.kernels.digit_read import pack_columns, pad_lanes, pad_to
 from repro.runtime import spans
@@ -157,26 +158,6 @@ def _lowest_bits(x: jnp.ndarray, k: int, width: int) -> jnp.ndarray:
         keep = keep | low
         x = x ^ low
     return keep
-
-
-def _exclusive_prefix(x: jnp.ndarray) -> jnp.ndarray:
-    """Exclusive prefix sum of 0/1 counts along the lanes of a (b, Np)
-    tile, Np a multiple of 128.  Each 128-lane chunk takes its in-chunk
-    prefix from one matmul with a strict upper-triangular 0/1 matrix (exact
-    in f32; the MXU does it), plus the running total of earlier chunks.
-    No ``cumsum``: Mosaic does not lower it along the lane axis."""
-    b, n = x.shape
-    row = jax.lax.broadcasted_iota(jnp.int32, (128, 128), 0)
-    col = jax.lax.broadcasted_iota(jnp.int32, (128, 128), 1)
-    tri = (row < col).astype(jnp.float32)          # tri[i, j]: i < j
-    base = jnp.zeros((b, 1), jnp.float32)
-    parts = []
-    for c in range(0, n, 128):
-        xc = x[:, c:c + 128].astype(jnp.float32)
-        parts.append(jnp.dot(xc, tri, preferred_element_type=jnp.float32)
-                     + base)
-        base = base + jnp.sum(xc, axis=1, keepdims=True)
-    return jnp.concatenate(parts, axis=1).astype(x.dtype)
 
 
 def _fused_tns_kernel(word_ref, rank_ref, cnt_ref, *,
@@ -281,7 +262,7 @@ def _fused_tns_kernel(word_ref, rank_ref, cnt_ref, *,
 
         # ---- emission: whole tie set, consecutive index-order ranks ----
         r = jnp.minimum(t, jnp.maximum(stop_n - out, 0))
-        p = _exclusive_prefix(isw.astype(jnp.int32))
+        p = exclusive_prefix(isw.astype(jnp.int32))
         emit_now = isw & (p < r) & running
         rank = jnp.where(emit_now, out + p, rank)
         out = out + jnp.where(running, r, 0)

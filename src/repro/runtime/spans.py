@@ -19,6 +19,8 @@ The ``sort.*`` vocabulary (one root ``sort`` span per call):
 * ``sort.encode`` - keys to bit planes or order-preserving unsigned keys;
 * ``sort.h2d`` - a host array handed to the device (counter ``h2d_bytes``);
 * ``sort.dispatch`` - calls of jitted functions (enqueue, not run time);
+  ``radix`` counts ``radix_dense``, 1 where its rows take the dense
+  one-hot passes of ``radix_select.radix_sort_keys``;
 * ``sort.readback`` - a device array read to the host, which waits for the
   device (counters ``readbacks``, ``d2h_bytes``);
 * ``sort.rank_to_perm`` - the rank ring inverted on the host (``pallas-tns``

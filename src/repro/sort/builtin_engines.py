@@ -175,6 +175,7 @@ def _radix(x, *, width, fmt, k, ascending, level_bits, stop_after,
     keys = _unsigned_keys(x, width, fmt, ascending)
     rr = r or (8 if width % 8 == 0 else 4)
     keys = spans.to_device(keys)
+    spans.count("radix_dense", int(rs.takes_dense_path(keys.shape[-1])))
     with spans.span("sort.dispatch"):
         perm = rs.radix_sort_keys(keys, r=rr)
     return _finish(x, perm, engine="radix", fmt=fmt, width=width,

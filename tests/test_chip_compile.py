@@ -1,4 +1,5 @@
-"""Compile the main path's Pallas kernels for a described TPU v5e chip.
+"""Compile the main path's Pallas kernels, and the full sort's XLA radix
+sort, for a described TPU v5e chip.
 
 Nothing runs.  Each test lowers a kernel at a real size with
 ``interpret=False`` and compiles it with the TPU compiler installed beside
@@ -16,6 +17,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.core import radix_select
 from repro.kernels import (bitplane_pack, digit_read, fused_tns,
                            masked_matmul, radix_topk)
 
@@ -74,3 +76,13 @@ def test_kernel_compiles_for_v5e(case, one_chip):
     shapes = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in args]
     compiled = jax.jit(fn).lower(*shapes).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_radix_sort_compiles_for_v5e_without_gathers(one_chip):
+    """The full-sort cell's shape takes the dense passes: matmuls and
+    elementwise work, no gather or scatter left for the chip to run one
+    element at a time."""
+    x = jax.ShapeDtypeStruct((128, 1024), jnp.uint32, sharding=one_chip)
+    hlo = radix_select.radix_sort_keys.lower(x, r=8).compile().as_text()
+    assert "convolution" in hlo
+    assert "gather(" not in hlo and "scatter(" not in hlo

@@ -1,4 +1,6 @@
 """Throughput-mode comparison-free selection vs. lax references."""
+import functools
+
 import numpy as np
 import pytest
 from _prop import given, settings, st
@@ -85,3 +87,89 @@ class TestRadixSort:
         perm = rs.radix_sort_keys(keys, r=8)
         out = np.asarray(keys)[np.asarray(perm)]
         np.testing.assert_array_equal(out, np.sort(np.array(data, np.uint32)))
+
+
+# ---------------------------------------------------------------------------
+# radix_sort_keys on both sides of the dense bound: the dense one-hot
+# passes (rows of at most DENSE_MAX_N keys) and the gather passes.
+# ---------------------------------------------------------------------------
+
+BOUND = rs.DENSE_MAX_N
+_DT_R = [(np.uint8, 4), (np.uint8, 8), (np.uint16, 4), (np.uint16, 8),
+         (np.uint32, 4), (np.uint32, 8)]
+_LEADS = [(), (3,), (2, 3)]
+
+
+def _keys(dtype, shape, kind, seed=0):
+    rng = np.random.default_rng(seed)
+    top = int(np.iinfo(dtype).max)
+    if kind == "random":
+        return rng.integers(0, top, shape, endpoint=True,
+                            dtype=np.uint64).astype(dtype)
+    if kind == "equal":
+        return np.full(shape, 5, dtype)
+    # extremal: only 0 and the largest key, many ties of each
+    return np.where(rng.random(shape) < 0.5, 0, top).astype(dtype)
+
+
+def _expect(keys, descending):
+    ref = np.argsort(keys, axis=-1, kind="stable")
+    return np.flip(ref, axis=-1) if descending else ref
+
+
+_PARITY = ([(dt, r, n, _LEADS[i % 3], i % 2 == 1, "random")
+            for i, (n, (dt, r)) in enumerate(
+                (n, dr) for n in (1, 2, 127, 1000, 1024) for dr in _DT_R)]
+           + [(np.uint32, 8, n, lead, desc, "random")
+              for n, lead, desc in ((BOUND, (), False), (BOUND, (3,), True),
+                                    (BOUND + 1, (), True),
+                                    (BOUND + 1, (2, 3), False))]
+           + [(dt, r, n, (), desc, kind)
+              for kind, dt, r in (("equal", np.uint16, 8),
+                                  ("extremal", np.uint32, 8))
+              for n in (1000, BOUND + 1) for desc in (False, True)])
+
+
+@pytest.mark.parametrize(
+    "dtype,r,n,lead,descending,kind", _PARITY,
+    ids=[f"{np.dtype(c[0]).name}-r{c[1]}-n{c[2]}-lead{len(c[3])}-"
+         f"{'desc' if c[4] else 'asc'}-{c[5]}" for c in _PARITY])
+def test_radix_sort_keys_matches_stable_argsort(dtype, r, n, lead,
+                                                descending, kind):
+    keys = _keys(dtype, lead + (n,), kind, seed=n)
+    got = rs.radix_sort_keys(jnp.asarray(keys), r=r, descending=descending)
+    assert got.shape == keys.shape and got.dtype == jnp.int32
+    np.testing.assert_array_equal(np.asarray(got), _expect(keys, descending))
+
+
+def test_dense_and_gather_passes_give_the_same_permutation(monkeypatch):
+    keys = _keys(np.uint32, (4, 1000), "random", seed=9) % 50  # ties
+    dense = rs.radix_sort_keys(jnp.asarray(keys), r=8)
+    monkeypatch.setattr(rs, "DENSE_MAX_N", 0)
+    gathers = jax.jit(rs.radix_sort_keys.__wrapped__,
+                      static_argnames=("r", "descending"))(
+        jnp.asarray(keys), r=8)
+    np.testing.assert_array_equal(np.asarray(dense), np.asarray(gathers))
+
+
+def _primitives(n):
+    jaxpr = jax.make_jaxpr(functools.partial(rs.radix_sort_keys, r=8))(
+        jax.ShapeDtypeStruct((2, n), jnp.uint32))
+    seen = set()
+
+    def walk(j):
+        for eqn in j.eqns:
+            seen.add(eqn.primitive.name)
+            for v in eqn.params.values():       # the jit's inner jaxpr
+                if hasattr(v, "jaxpr"):
+                    walk(v.jaxpr)
+    walk(jaxpr.jaxpr)
+    return seen
+
+
+@pytest.mark.parametrize("n", [1024, BOUND, BOUND + 1])
+def test_no_comparison_sort_and_no_gathers_on_the_dense_path(n):
+    prims = _primitives(n)
+    assert not prims & {"sort", "top_k", "argsort"}, prims
+    moves = prims & {"gather", "scatter"}
+    assert (not moves) if rs.takes_dense_path(n) else moves, prims

@@ -13,11 +13,18 @@ import numpy as np
 import pytest
 
 from repro import sort as S
+from repro.core import radix_select as rs
 from repro.runtime import spans
 
 B, N = 4, 128
 X8 = np.random.default_rng(3).integers(0, 256, (B, N)).astype(np.uint8)
 X32 = np.random.default_rng(4).integers(0, 2**32, (B, N), dtype=np.uint32)
+X32_CELL = np.random.default_rng(5).integers(0, 2**32, (128, 1024),
+                                             dtype=np.uint32)
+X32_LONG = np.random.default_rng(6).integers(
+    0, 2**32, (1, rs.DENSE_MAX_N + 1), dtype=np.uint32)
+RADIX_SPANS = ["sort.encode", "sort.h2d", "sort.dispatch", "sort.readback",
+               "sort.finish"]
 
 # case: (engine, input, stop_after, child spans in order, counters)
 CALLS = {
@@ -37,11 +44,17 @@ CALLS = {
                          "sort.rank_to_perm", "sort.finish"],
                         {"h2d_bytes": B * 8 * N, "readbacks": 1,
                          "d2h_bytes": B * (4 + N) * 4, "device_perm": 0}),
-    "radix": ("radix", X32, None, ["sort.encode", "sort.h2d",
-                                   "sort.dispatch", "sort.readback",
-                                   "sort.finish"],
+    "radix": ("radix", X32, None, RADIX_SPANS,
               {"h2d_bytes": B * N * 4, "readbacks": 1,
-               "d2h_bytes": B * N * 4}),
+               "d2h_bytes": B * N * 4, "radix_dense": 1}),
+    # the full-sort cell's shape takes the dense one-hot passes; a row
+    # longer than their bound takes the gather passes
+    "radix-dense": ("radix", X32_CELL, None, RADIX_SPANS,
+                    {"h2d_bytes": X32_CELL.size * 4, "readbacks": 1,
+                     "d2h_bytes": X32_CELL.size * 4, "radix_dense": 1}),
+    "radix-long": ("radix", X32_LONG, None, RADIX_SPANS,
+                   {"h2d_bytes": X32_LONG.size * 4, "readbacks": 1,
+                    "d2h_bytes": X32_LONG.size * 4, "radix_dense": 0}),
 }
 
 
