@@ -69,11 +69,8 @@ def use_ref(interpret: Optional[bool] = None) -> bool:
 
 
 def env_stamp() -> dict:
-    """Provenance stamp for benchmark artifacts: which backend, chip,
-    device count, JAX version and Pallas dispatch mode produced the
-    numbers.  Every BENCH_* writer embeds this so a committed artifact can
-    be told apart from a rerun on different hardware (a compiled-TPU
-    baseline must not gate an interpret-CPU run, and vice versa)."""
+    """Provenance stamp for a run's output: which backend, chip, device
+    count, JAX version and Pallas dispatch mode produced the numbers."""
     devices = jax.devices()
     return {
         "backend": jax.default_backend(),

@@ -7,7 +7,7 @@ registry, and sustained-throughput metrics (p50/p99 latency, batch
 occupancy, evictions) on a simulated device clock.
 
     PYTHONPATH=src python -m repro.launch.serve --requests 40 --n 48 \
-        --mean-gap-us 0.1 --out BENCH_serve.json
+        --mean-gap-us 0.1 --out serve.json
 
 ``--oneshot`` keeps the original model-decode driver: batched prefill +
 decode with the paper's technique in the loop (comparison-free top-k

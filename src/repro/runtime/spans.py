@@ -15,7 +15,6 @@ tracing, not running.
 
 The ``sort.*`` vocabulary (one root ``sort`` span per call):
 
-* ``sort.params`` - the autotune table lookup;
 * ``sort.encode`` - keys to bit planes or order-preserving unsigned keys;
 * ``sort.h2d`` - a host array handed to the device (counter ``h2d_bytes``);
 * ``sort.dispatch`` - calls of jitted functions (enqueue, not run time);

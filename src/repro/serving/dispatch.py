@@ -46,30 +46,6 @@ PALLAS_TOPK_MAX = 32
 # magnitude slower than the vectorized throughput engines.
 _WALL_PRIOR_US = {"latency": 100.0, "throughput": 1.0}
 
-
-def _pallas_tns_wall_prior() -> Optional[float]:
-    """us per emission for ``pallas-tns`` from the committed autotune
-    table (``BENCH_pallas_tns.json``), so the dispatcher's first estimate
-    reflects the kernel's *measured* cost in the current pallas mode
-    rather than the generic throughput prior.  Median over tuned cells of
-    best-config us amortized per emission per instance; None when no cell
-    was tuned under this mode."""
-    from repro.kernels import autotune, backend
-    suffix = f"|{backend.mode()}"
-    vals = []
-    for key, row in autotune.default_table().items():
-        if not key.endswith(suffix):
-            continue
-        try:
-            m, b = (int(part[1:]) for part in key.split("|")[2:4])
-            vals.append(float(row["us"]) / max(1, m * b))
-        except (KeyError, ValueError, TypeError):
-            continue
-    if not vals:
-        return None
-    vals.sort()
-    return vals[len(vals) // 2]
-
 # Repair-ladder cycle premium assumed for resilient wrappers under an
 # active fault process until the EWMA has real measurements.
 _RESILIENT_PREMIUM = 2.0
@@ -129,7 +105,6 @@ class Dispatcher:
         # their latency estimates in one deterministic domain)
         self.throughput_elem_us = throughput_elem_us
         self._anchor_cpn = _anchor_cycles_per_number()
-        self._pallas_tns_prior = _pallas_tns_wall_prior()
         self._cpe: Dict[str, Ewma] = {}      # cycles per emission
         self._wpe: Dict[str, Ewma] = {}      # wall us per emission
         self._qual: Dict[str, Ewma] = {}     # observed emission quality
@@ -222,8 +197,6 @@ class Dispatcher:
         wpe = self._wpe.get(name)
         if wpe is not None and wpe.value is not None:
             wall_per = wpe.value
-        elif name == "pallas-tns" and self._pallas_tns_prior is not None:
-            wall_per = self._pallas_tns_prior
         else:
             wall_per = _WALL_PRIOR_US[spec.mode]
         return Estimate(engine=name, latency_us=latency_us,

@@ -67,7 +67,7 @@ class TickStats:
 @dataclasses.dataclass
 class ServeStats:
     """Accumulated over one orchestrator run; ``summary()`` is the
-    BENCH_serve payload."""
+    run's report (what ``launch/serve.py --out`` writes)."""
     ticks: List[TickStats] = dataclasses.field(default_factory=list)
     latencies_us: List[float] = dataclasses.field(default_factory=list)
     engine_counts: Dict[str, int] = dataclasses.field(default_factory=dict)

@@ -115,7 +115,7 @@ def sort(x, *, engine: str = "tns", fmt: Optional[str] = None,
 
 def engines():
     """name -> EngineSpec of everything registered (the reconfigurability
-    menu; benchmarks enumerate this)."""
+    menu; the parity tests enumerate this)."""
     return available_engines()
 
 

@@ -222,17 +222,17 @@ def _topk_keys_dispatch(keys: jnp.ndarray, m: int):
                       "cycle/DR parity with the while_loop machine "
                       "(interpret on CPU, compiled on TPU)")
 def _pallas_tns(x, *, width, fmt, k, ascending, level_bits, stop_after,
-                block_rows=None, unroll=None, **kw):
+                **kw):
     if level_bits != 1:
         raise EngineUnsupported(
             "pallas-tns runs binary (level_bits=1) planes; multi-level "
             "stays on the 'ml' while_loop machine")
-    from repro.kernels import autotune, fused_tns
+    from repro.kernels import fused_tns
     xb = np.asarray(x)
     squeeze = xb.ndim == 1
     if squeeze:
         xb = xb[None]
-    b, n = xb.shape
+    n = xb.shape[1]
     if n >= (1 << 15):
         raise EngineUnsupported(
             "pallas-tns supports N < 32768 per bank (same packed-count "
@@ -242,16 +242,9 @@ def _pallas_tns(x, *, width, fmt, k, ascending, level_bits, stop_after,
             "pallas-tns packs a lane's digit column into one int32 key; "
             "width <= 30 required (32-bit data stays on the while_loop "
             "machines)")
-    m = n if stop_after is None else min(stop_after, n)
-    if block_rows is None and unroll is None:
-        # the committed autotune table picks the grid shape per cell
-        with spans.span("sort.params"):
-            params = autotune.best_params(fmt, n, m, b)
-        block_rows = params["block_rows"] or None
-        unroll = params["unroll"]
     out = fused_tns.fused_tns_sort(
         xb, width=width, k=k, fmt=fmt, ascending=ascending,
-        stop_after=stop_after, block_rows=block_rows, unroll=unroll or 1)
+        stop_after=stop_after, block_rows=None, unroll=1)
     perm, cycles, drs, rlc = out[:4]
     if squeeze:
         perm, cycles, drs, rlc = perm[0], cycles[0], drs[0], rlc[0]

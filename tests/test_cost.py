@@ -5,6 +5,7 @@ loud and its anchors exact.
 """
 import math
 
+import numpy as np
 import pytest
 
 from repro.core import cost
@@ -84,3 +85,45 @@ class TestGoldenTableS5:
         point = cost.operating_point("bts")
         m = cost.sort_metrics(32768, 1024, point)
         assert m.throughput_num_per_us == pytest.approx(19.53, abs=0.01)
+
+
+# Engine calls at the Table S5 operating points (1024 x 32-bit).
+S5_ENGINES = {"tns": dict(k=4), "mb": dict(k=6, banks=2),
+              "ml": dict(k=1, level_bits=4),
+              "bitslice": dict(k=4, slice_widths=[8, 24])}
+
+
+@pytest.fixture(scope="module")
+def s5_metrics():
+    """The engines' metrics, from cycles counted on 1,024 seeded uniform
+    32-bit keys."""
+    from repro import sort
+    x = np.random.default_rng(0).integers(0, 2**32, 1024, dtype=np.uint64)
+    return {name: sort.sort(x, engine=name, width=32, fmt="unsigned",
+                            **kw).metrics()
+            for name, kw in S5_ENGINES.items()}
+
+
+class TestHeadlineClaims:
+    """The abstract's ranges against the ASIC merge sorter: "3.32x~7.70x
+    speedup, 6.23x~183.5x energy efficiency improvement and 2.23x~7.43x
+    area reduction", over the TNS / CA-TNS configurations.  The best
+    speedup and energy ratios reach the claim (at most 15% past its top);
+    the smallest area reduction lies inside it."""
+
+    @pytest.mark.parametrize("claim", ["speedup", "energy", "area"])
+    def test_against_asic_merge(self, s5_metrics, claim):
+        asic = cost.REFERENCE_SYSTEMS["asic_merge"]
+        if claim == "speedup":
+            best = max(m.throughput_num_per_us / asic["thpt"]
+                       for m in s5_metrics.values())
+            assert 3.32 <= best <= 7.70 * 1.15
+        elif claim == "energy":
+            best = max(m.energy_eff / asic["energy_eff"]
+                       for m in s5_metrics.values())
+            assert 100.0 <= best <= 183.5 * 1.15
+        else:
+            asic_area = asic["thpt"] / 1e3 / asic["area_eff"]     # mm^2
+            least = min(asic_area / m.area_mm2
+                        for m in s5_metrics.values())
+            assert 2.0 <= least <= 7.43

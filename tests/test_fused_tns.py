@@ -11,14 +11,10 @@
   ``DEVICE_PERM_MAX`` emissions, the permutation's slots found on the
   device; both equal what the host reads off the raw rank ring and
   counter block.
-* Autotune: the (block_rows, unroll) knobs never change results, and the
-  table round-trips through save/load with mode-scoped nearest-cell
-  lookup.
-* Engine/serving integration: ``pallas-tns`` through the sort facade,
-  and the dispatcher's autotune-derived wall prior.
+* Grid shape: the kernel's (block_rows, unroll) parameters never change
+  results.
+* Engine integration: ``pallas-tns`` through the sort facade.
 """
-import json
-
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -27,7 +23,7 @@ import pytest
 from repro import sort as S
 from repro.core import bitplane as bp
 from repro.core import tns as jt
-from repro.kernels import autotune, backend, fused_tns
+from repro.kernels import backend, fused_tns
 
 RNG = np.random.default_rng(11)
 
@@ -70,6 +66,11 @@ class TestParity:
     def test_contract_grid(self, fmt, n, k):
         # 130 is deliberately not a multiple of the 128 lane width
         _check_cell(fmt, n, 3, k=k, stop_after=min(6, n))
+
+    @pytest.mark.parametrize("fmt,n,b,k,stop_after", [
+        (bp.UNSIGNED, 1024, 64, 0, 2), (bp.FLOAT, 256, 8, 2, 8)])
+    def test_batches_of_many_lane_tiles(self, fmt, n, b, k, stop_after):
+        _check_cell(fmt, n, b, k=k, stop_after=stop_after)
 
     @pytest.mark.parametrize("fmt", [bp.UNSIGNED, bp.FLOAT])
     def test_full_sort(self, fmt):
@@ -159,7 +160,7 @@ class TestEpilogue:
             np.testing.assert_array_equal(getattr(got, field), cnt[:, col])
 
 
-class TestAutotune:
+class TestGridShape:
     @pytest.mark.parametrize("knobs", [
         dict(block_rows=1, unroll=1),
         dict(block_rows=2, unroll=2),
@@ -177,39 +178,6 @@ class TestAutotune:
                 np.asarray(getattr(got, field)),
                 np.asarray(getattr(ref, field)))
 
-    def test_table_roundtrip(self, tmp_path):
-        mode = backend.mode()
-        table = {autotune.cell_key("unsigned", 1024, 2, 64, mode):
-                 {"block_rows": 16, "unroll": 2, "us": 100.0},
-                 autotune.cell_key("float", 256, 8, 32, mode):
-                 {"block_rows": 0, "unroll": 1, "us": 50.0}}
-        path = tmp_path / "table.json"
-        autotune.save_table(table, path)
-        assert autotune.load_table(path) == table
-        # exact hit
-        assert autotune.best_params("unsigned", 1024, 2, 64,
-                                    table=table) == \
-            {"block_rows": 16, "unroll": 2}
-        # nearest same-fmt cell (shape distance, not exact)
-        assert autotune.best_params("unsigned", 512, 4, 64,
-                                    table=table) == \
-            {"block_rows": 16, "unroll": 2}
-        # unknown fmt+mode falls back to defaults
-        assert autotune.best_params("twos", 512, 4, 64, table=table) == \
-            autotune.DEFAULT_PARAMS
-        # a different mode never reuses this table's cells
-        assert autotune.best_params("unsigned", 1024, 2, 64, table=table,
-                                    mode="compiled-nonexistent") == \
-            autotune.DEFAULT_PARAMS
-
-    def test_committed_artifact_is_loadable(self):
-        # the repo-root BENCH artifact doubles as the default table
-        table = autotune.default_table()
-        if not table:
-            pytest.skip("no committed BENCH_pallas_tns.json")
-        for key, row in table.items():
-            assert {"block_rows", "unroll", "us"} <= set(row)
-
 
 class TestEngineIntegration:
     def test_facade_matches_oracle(self):
@@ -221,15 +189,6 @@ class TestEngineIntegration:
         np.testing.assert_array_equal(np.asarray(res.indices)[:8],
                                       np.asarray(ref.indices)[:8])
         assert int(np.sum(res.cycles)) == int(np.sum(ref.cycles))
-
-    def test_dispatch_wall_prior_reads_autotune_table(self, monkeypatch):
-        from repro.serving import dispatch
-        key = autotune.cell_key("unsigned", 1024, 2, 64)
-        monkeypatch.setattr(
-            autotune, "default_table",
-            lambda: {key: {"block_rows": 0, "unroll": 1, "us": 1280.0}})
-        # 1280us / (m=2 x b=64 emissions) = 10us per emission
-        assert dispatch._pallas_tns_wall_prior() == pytest.approx(10.0)
 
     def test_env_stamp_fields(self):
         stamp = backend.env_stamp()

@@ -265,6 +265,14 @@ class TestResilientWrapper:
         # a full permutation is still returned (best effort)
         assert sorted(res.indices.tolist()) == list(range(64))
 
+    def test_operating_ber_keeps_quality(self):
+        # the calibrated 3-bit multi-level cell's read noise
+        x = _data(64, seed=4)
+        with faults.inject(faults.FaultSpec(ber=dm.operating_ber(3),
+                                            seed=4)):
+            res = sort_engine.sort(x, engine="resilient:tns")
+        assert res.quality >= 0.99 and not res.degraded
+
     def test_voting_alone_fixes_moderate_ber(self):
         x = _data(64, seed=8)
         with faults.inject(faults.FaultSpec(ber=0.01, seed=2)):

@@ -10,6 +10,7 @@ import pytest
 
 from repro import serving
 from repro.runtime import faults
+from repro.serving import dispatch
 from repro.serving.request import ENERGY, LATENCY, WALL
 from repro.serving.metrics import percentile
 from repro.serving.request import Status, priority_key
@@ -188,7 +189,16 @@ class TestDispatcher:
     def test_wall_objective_picks_throughput_engine(self):
         d = serving.Dispatcher()
         pick = d.select(_req(n=64, m=8, objective=WALL))
-        assert pick.engine in ("pallas-topk", "radix")
+        # every throughput engine starts from the same wall prior, so
+        # the name breaks the tie
+        assert pick.engine == "pallas-tns"
+
+    def test_pallas_tns_starts_from_the_throughput_prior(self):
+        d = serving.Dispatcher()
+        req = _req(n=64, m=8)
+        prior = dispatch._WALL_PRIOR_US["throughput"] * req.target
+        for name in ("pallas-tns", "radix"):
+            assert d.estimate(name, self._spec(name), req).wall_us == prior
 
     def test_infeasible_budget_degrades_to_best_effort(self):
         d = serving.Dispatcher()
@@ -308,6 +318,42 @@ class TestOrchestrator:
         assert orch.submit(_req(rid=0, priority=3))
         assert not orch.submit(_req(rid=1, priority=3))
         assert orch.stats.accepted == 1 and orch.stats.rejected == 1
+
+    @pytest.fixture(scope="class")
+    def saturated(self):
+        """Continuous batching and the one-shot loop on one saturated
+        trace (arrivals far faster than service)."""
+        def trace():
+            return serving.make_trace(12, seed=0, n=32, mean_gap_us=0.05)
+        orch = serving.Orchestrator(
+            clock=serving.SimulatedClock(),
+            cfg=serving.OrchestratorConfig(chunk=16))
+        return orch.run(trace()), serving.oneshot_loop(trace())
+
+    def test_continuous_batching_beats_oneshot(self, saturated):
+        # a batched step costs the max of its members' cycles, the
+        # one-shot loop the sum
+        cont, ones = saturated
+        assert cont["completed"] == cont["accepted"] == 12
+        assert cont["failed"] == 0
+        assert cont["throughput_elems_per_us"] > \
+            ones["throughput_elems_per_us"]
+
+    def test_dispatch_spreads_over_engines(self, saturated):
+        assert len(saturated[0]["engines"]) >= 3
+
+    def test_faulted_trace_uses_verified_engines(self):
+        trace = serving.make_trace(6, seed=1, n=32, mean_gap_us=0.05,
+                                   classes=("bulk-latency", "float-latency"),
+                                   quality_floor=0.99)
+        orch = serving.Orchestrator(
+            clock=serving.SimulatedClock(),
+            cfg=serving.OrchestratorConfig(chunk=16))
+        with faults.inject(faults.FaultSpec(ber=0.01, seed=0)):
+            rep = orch.run(trace)
+        assert rep["completed"] == rep["accepted"] == 6
+        assert all(e.startswith("resilient:") or e == "mb-ft"
+                   for e in rep["engines"])
 
     def test_oneshot_loop_equal_mix(self):
         trace = serving.make_trace(4, seed=0, n=32, mean_gap_us=0.05)

@@ -29,8 +29,8 @@ RADIX_SPANS = ["sort.encode", "sort.h2d", "sort.dispatch", "sort.readback",
 # case: (engine, input, stop_after, child spans in order, counters)
 CALLS = {
     "pallas-tns": ("pallas-tns", X8, 1,
-                   ["sort.params", "sort.encode", "sort.h2d",
-                    "sort.dispatch", "sort.readback", "sort.finish"],
+                   ["sort.encode", "sort.h2d", "sort.dispatch",
+                    "sort.readback", "sort.finish"],
                    # 8 uint8 planes in; one int32 array out: the four
                    # counter lanes and the permutation's first slot, found
                    # on the device
@@ -39,9 +39,9 @@ CALLS = {
     # past DEVICE_PERM_MAX emissions the rank ring comes back with the
     # counter lanes, still in one readback, and the host inverts it
     "pallas-tns-ring": ("pallas-tns", X8, 33,
-                        ["sort.params", "sort.encode", "sort.h2d",
-                         "sort.dispatch", "sort.readback",
-                         "sort.rank_to_perm", "sort.finish"],
+                        ["sort.encode", "sort.h2d", "sort.dispatch",
+                         "sort.readback", "sort.rank_to_perm",
+                         "sort.finish"],
                         {"h2d_bytes": B * 8 * N, "readbacks": 1,
                          "d2h_bytes": B * (4 + N) * 4, "device_perm": 0}),
     "radix": ("radix", X32, None, RADIX_SPANS,
@@ -125,6 +125,18 @@ def profiling(tmp_path):
     assert jax.profiler.TraceAnnotation.is_enabled()
     yield
     jax.profiler.stop_trace()
+
+
+def test_pallas_tns_records_only_the_call_steps(profiling):
+    # the engine takes its grid shape from its arguments: no step of the
+    # call looks anything up
+    spans.clear()
+    S.sort(X8, engine="pallas-tns", k=2, stop_after=1)
+    recs = spans.records()
+    spans.clear()
+    assert [r.name for r in recs if r.parent != -1] == [
+        "sort.encode", "sort.h2d", "sort.dispatch", "sort.readback",
+        "sort.finish"]
 
 
 def test_nesting_counters_and_overflow_of_a_recorder(profiling):

@@ -1,6 +1,6 @@
 #!/usr/bin/env bash
-# Tier-1 CI: full test suite on CPU + a fast smoke pass over the
-# sort-engine registry.  Mirrors .github/workflows/ci.yml.
+# Tier-1 CI: static analysis and the full test suite on CPU.
+# Mirrors .github/workflows/ci.yml.
 set -euo pipefail
 cd "$(dirname "$0")/.."
 
@@ -12,15 +12,3 @@ python -m repro.analysis src/ --trace-gate
 
 echo "== tier-1 tests =="
 python -m pytest -x -q
-
-echo "== sort-engine registry smoke =="
-python -m benchmarks.run --smoke
-
-echo "== fault-injection smoke =="
-python -m benchmarks.run --smoke-faults
-
-echo "== serving-loop smoke =="
-python -m benchmarks.run --smoke-serve
-
-echo "== fused Pallas TNS smoke (parity + perf gate) =="
-python -m benchmarks.run --smoke-pallas
